@@ -131,13 +131,17 @@ def csv_row(name: str, us_per_call: float, derived: str) -> str:
     return row
 
 
-def platform_meta(ffn_backend: str | None = None) -> Dict[str, str]:
-    """Provenance stamp for every bench leg: which backend produced the
-    numbers. ``ffn_backend`` records the expert-FFN implementation the
-    leg ran (CLI flag or REPRO_FFN_BACKEND; 'default' when unpinned)."""
+def platform_meta(ffn_backend: str | None = None) -> Dict[str, object]:
+    """Provenance stamp for every bench leg: which backend and devices
+    produced the numbers, so a CPU leg is never read as a chip one.
+    ``ffn_backend`` records the expert-FFN implementation the leg ran
+    (CLI flag or REPRO_FFN_BACKEND; 'default' when unpinned)."""
+    dev = jax.devices()[0]
     return {
         "platform": str(jax.default_backend()),
-        "device_kind": str(jax.devices()[0].device_kind),
+        "device_platform": str(dev.platform),
+        "device_kind": str(dev.device_kind),
+        "device_count": len(jax.devices()),
         "ffn_backend": str(
             ffn_backend or os.environ.get("REPRO_FFN_BACKEND") or "default"
         ),

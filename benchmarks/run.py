@@ -22,6 +22,9 @@ def main() -> None:
                    help="comma-separated bench names")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bit_allocation,
         kernel_bench,

@@ -77,12 +77,13 @@ def calibrate(params, tokens: jnp.ndarray, cfg, max_tokens: int = 16384):
     """Run calibration batches through the fp model, capturing MoE inputs
     and router statistics (paper §3.2.2)."""
     assert cfg.is_moe, "calibration targets MoE archs"
-    blocks = tf.unstack_blocks(params, cfg)
     x = jnp.take(params["embed"], tokens, axis=0)
     windows = tf.layer_windows_static(cfg, tokens.shape[1])
-    stats = [significance.RouterStats(cfg.num_experts) for _ in blocks]
+    stats = [
+        significance.RouterStats(cfg.num_experts) for _ in range(cfg.num_layers)
+    ]
     moe_inputs = []
-    for l, p_l in enumerate(blocks):
+    for l, p_l in enumerate(tf.iter_blocks(params, cfg)):
         x, h2 = _block_parts(p_l, x, cfg, int(windows[l]))
         t = h2.reshape(-1, cfg.d_model)
         keep = min(max_tokens, t.shape[0])
@@ -113,10 +114,9 @@ def compute_eps(
     bit_choices=(1, 2, 3), group: int = 128, eps_tokens: int = 2048,
 ) -> np.ndarray:
     """``eps[L, E, |bits|]`` via Eq. 6 on captured calibration inputs."""
-    blocks = tf.unstack_blocks(params, cfg)
     L_, E = cfg.num_layers, cfg.num_experts
     eps = np.zeros((L_, E, len(bit_choices)))
-    for l, p_l in enumerate(blocks):
+    for l, p_l in enumerate(tf.iter_blocks(params, cfg)):
         h2 = jnp.asarray(calib.moe_inputs[l][:eps_tokens])[None]  # [1, T, D]
         experts = p_l["moe"]["experts"]
         ew_list = [
@@ -196,9 +196,8 @@ def compress_model(
     paper's average-bit accounting).
     """
     q = cfg.quant
-    blocks = tf.unstack_blocks(params, cfg)
     blocks_c = []
-    for l, p_l in enumerate(blocks):
+    for l, p_l in enumerate(tf.iter_blocks(params, cfg)):
         h2 = calib.moe_inputs[l][:gptq_tokens].astype(np.float64)
         experts = p_l["moe"]["experts"]
         gptq_results = None

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from .quant_matmul import _unpack_pow2_tile
 
 __all__ = ["binary_matmul_pallas"]
 
@@ -31,9 +31,8 @@ def _kernel(x_ref, b_ref, a_ref, o_ref, acc_ref, *, nk: int, compute_dtype):
 
     bk = x_ref.shape[1]
     bn = o_ref.shape[1]
-    shifts = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
-    bits01 = ((b_ref[...][:, None, :] >> shifts) & 1).reshape(bk, bn)
-    w = (bits01.astype(compute_dtype) * 2 - 1)  # ±1; alpha applied at the end
+    bits01 = _unpack_pow2_tile(b_ref[...], 1, bk, bn)
+    w = (bits01.astype(jnp.float32) * 2 - 1).astype(compute_dtype)  # ±1; alpha at the end
     acc_ref[...] += jnp.dot(
         x_ref[...].astype(compute_dtype), w, preferred_element_type=jnp.float32
     )
@@ -78,7 +77,7 @@ def binary_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -46,8 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 from .quant_matmul import _dequant, _unpack_tile
 
 __all__ = [
@@ -177,7 +175,7 @@ def moe_gmm_pallas(
         kernel,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -290,7 +288,7 @@ def moe_gmm_swiglu_pallas(
         kernel,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -75,6 +75,7 @@ def quant_matmul(
         return y.reshape(*lead, n)
     m = x2.shape[0]
     bm_ = min(bm, _next_mult(m, 8))
+    bn_, bk_ = _gmm_blocks(n, k, pt.group, bn, bk)
     x2p = _pad_to(x2, bm_, 0)
     y = quant_matmul_pallas(
         x2p,
@@ -84,8 +85,8 @@ def quant_matmul(
         bits=pt.bits,
         group=pt.group,
         bm=bm_,
-        bn=bn,
-        bk=bk,
+        bn=bn_,
+        bk=bk_,
         interpret=(backend == "interpret"),
     )
     return y[:m].reshape(*lead, n)
@@ -164,10 +165,26 @@ def binary_matmul(
 
 
 def _gmm_blocks(n: int, k: int, group: int, bn: int, bk: int):
-    """Clamp default bn/bk to shapes the Pallas kernel's asserts accept."""
-    bn_ = bn if n % min(bn, n) == 0 else n
-    bk_ = bk if (k % min(bk, k) == 0 and min(bk, k) % group == 0) else k
-    return bn_, bk_
+    """``(bn, bk)`` near the requested sizes that the TPU tiling accepts.
+
+    A block's last two dims must be multiples of (8, 128) or span the
+    array. So ``bn`` is a multiple of 128 dividing ``n``, else all of
+    ``n``; and since the scale/zero block holds ``bk // group`` rows,
+    ``bk`` is a multiple of ``8 * group`` dividing ``k``, else all of
+    ``k``.
+    """
+    return (
+        _aligned_divisor(n, 128, bn) or n,
+        _aligned_divisor(k, 8 * group, max(bk, 8 * group)) or k,
+    )
+
+
+def _aligned_divisor(total: int, step: int, cap: int) -> int:
+    """Largest multiple of ``step`` that divides ``total`` and is at most
+    ``cap`` (0 when there is none)."""
+    return next(
+        (b for b in range(cap - cap % step, 0, -step) if total % b == 0), 0
+    )
 
 
 def moe_gmm(

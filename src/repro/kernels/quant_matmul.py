@@ -17,9 +17,13 @@ Layouts
 * grid (M/bm, N/bn, K/bk) with K innermost ("arbitrary" semantics), f32
   VMEM scratch accumulator, ``bk`` a multiple of ``group``.
 
-MXU alignment: bm/bn multiples of 128; bk multiple of max(group, 128).
-Defaults (bm=256, bn=256, bk=512) keep the VMEM working set ≈
-256·512·4 + 512·256/4 + 2·256·512·4 + 256·256·4 ≈ 1.6 MiB « 16 MiB.
+TPU tiling: each block's last two dims are multiples of (8, 128) or the
+array's own, so the :mod:`repro.kernels.ops` wrappers pick ``bn`` as a
+multiple of 128 dividing N and ``bk`` as a multiple of ``8·group``
+dividing K (the scale block has ``bk/group`` rows), else the whole dim.
+Codes unpack in int32, as Mosaic casts uint8 to no float type; the
+[bk, bn] int32 and f32 tiles are the largest VMEM temporaries (1 MiB
+each at 1024 x 256).
 """
 from __future__ import annotations
 
@@ -31,13 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 __all__ = ["quant_matmul_pallas"]
 
 
 def _unpack_tile(w_ref, bits: int, bk: int, bn: int) -> jnp.ndarray:
-    """uint8 packed tile -> [bk, bn] uint8 codes (VPU shifts, no HBM)."""
+    """uint8 packed tile -> [bk, bn] int32 codes (VPU shifts, no HBM)."""
     if bits == 3:
         hi = _unpack_pow2_tile(w_ref[0][...], 2, bk, bn)
         lo = _unpack_pow2_tile(w_ref[1][...], 1, bk, bn)
@@ -47,9 +49,10 @@ def _unpack_tile(w_ref, bits: int, bk: int, bn: int) -> jnp.ndarray:
 
 def _unpack_pow2_tile(packed: jnp.ndarray, bits: int, bk: int, bn: int):
     per = 8 // bits
-    # [bk/per, bn] -> [bk/per, per, bn] -> [bk, bn]
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits)[None, :, None]
-    vals = (packed[:, None, :] >> shifts) & ((1 << bits) - 1)
+    # [bk/per, bn] -> [bk/per, per, bn] -> [bk, bn]. The shifts run in
+    # int32: Mosaic casts uint8 only to wider integers, never to float.
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, per, 1), 1) * bits
+    vals = (packed.astype(jnp.int32)[:, None, :] >> shifts) & ((1 << bits) - 1)
     return vals.reshape(bk, bn)
 
 
@@ -154,7 +157,7 @@ def quant_matmul_pallas(
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

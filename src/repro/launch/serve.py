@@ -49,7 +49,11 @@ The default path is :class:`repro.serving.engine.PagedServingEngine`:
 comparison (``--legacy``): it pads every wave with dummy requests and
 re-prefills per wave — the baseline the paged engine exists to beat.
 
-Runs reduced configs end-to-end on CPU (examples/serve_batched.py).
+By default it serves the arch's reduced CPU-sized twin
+(examples/serve_batched.py). ``--published`` serves the registry config
+at its published widths, with ``--layers N`` cutting only the depth —
+the same engine construction ``chip_smoke.py`` drives on a TPU. Weights,
+calibration tokens and prompts all come from ``--seed``.
 """
 from __future__ import annotations
 
@@ -66,8 +70,12 @@ from ..configs.registry import ARCH_IDS, get_config
 from ..models.registry import get_model
 from ..serving import EngineConfig, PagedServingEngine
 from ..serving import Request as PagedRequest
+from .compile_cache import enable_compile_cache
 
-__all__ = ["BatchedServer", "Request", "main"]
+__all__ = [
+    "BatchedServer", "Request", "build_engine", "build_model", "main",
+    "parser", "serving_config",
+]
 
 
 @dataclasses.dataclass
@@ -162,13 +170,13 @@ class BatchedServer:
         }
 
 
-def _compress_for_serving(cfg, params):
+def _compress_for_serving(cfg, params, seed: int = 0):
     """PMQ-compress the demo model on synthetic calibration tokens (the
     layer-uniform stacked layout from repro.core.pipeline — the same
     layout benchmarks/serving_latency.py serves)."""
     from ..core import pipeline
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     tokens = jnp.asarray(
         rng.integers(0, cfg.vocab_size, (4, 64)).astype(np.int32)
     )
@@ -198,10 +206,18 @@ def _parse_tenant_weights(spec: str):
     return tuple(pairs)
 
 
-def main() -> None:
+def parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", choices=ARCH_IDS, default="moonshot-v1-16b-a3b")
+    p.add_argument("--published", action="store_true",
+                   help="serve the registry config at its published widths "
+                        "(default: its reduced CPU-sized twin)")
+    p.add_argument("--layers", type=int, default=None, metavar="N",
+                   help="cut the depth to N layers (widths untouched)")
+    p.add_argument("--seed", type=int, default=0,
+                   help="seed of the weights, calibration tokens and prompts")
     p.add_argument("--requests", type=int, default=8)
+    p.add_argument("--prompt-len", type=int, default=24)
     p.add_argument("--max-new", type=int, default=16)
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--block-size", type=int, default=16)
@@ -309,7 +325,13 @@ def main() -> None:
                    help="span tracing detail (default: 'full' when "
                         "--trace-out is given, else 'off'); lifecycle "
                         "metrics are identical at every level")
-    args = p.parse_args()
+    return p
+
+
+def _check_args(args) -> None:
+    """Refuse flag combinations the chosen server cannot honour."""
+    if args.layers is not None and args.layers < 1:
+        raise SystemExit("--layers must be >= 1")
     if args.legacy and (args.prefix_cache or args.kv_bits is not None):
         # both features live in the paged KV pool — the wave batcher has
         # neither pages nor a prefix index
@@ -338,20 +360,6 @@ def main() -> None:
         raise SystemExit("--tenant-weights only applies to --policy fair")
     if args.ttft_budget_ms is not None and args.ttft_budget_ms < 0:
         raise SystemExit("--ttft-budget-ms must be >= 0")
-    tenant_weights = (
-        _parse_tenant_weights(args.tenant_weights)
-        if args.tenant_weights else None
-    )
-    trace_level = args.trace_level or ("full" if args.trace_out else "off")
-    if args.ffn_backend:
-        # process default too, so the --legacy wave batcher (no engine
-        # config, plain decode_step) honors the same A/B knob
-        import os
-
-        os.environ["REPRO_FFN_BACKEND"] = args.ffn_backend
-    cfg = get_config(args.arch).reduced()
-    bundle = get_model(cfg)
-    params = bundle.init(jax.random.PRNGKey(0))
     if args.resident_experts is not None and args.legacy:
         # the wave batcher has no offload path — refuse rather than
         # silently serve everything device-resident
@@ -365,27 +373,59 @@ def main() -> None:
                          "--resident-experts")
     if args.host_expert_bytes is not None and args.offload_dir is None:
         raise SystemExit("--host-expert-bytes requires --offload-dir")
+
+
+def serving_config(args):
+    """The arch's config at reduced or published widths, with the depth
+    cut to ``--layers`` when given."""
+    cfg = get_config(args.arch)
+    if not args.published:
+        cfg = cfg.reduced()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def build_model(args):
+    """``(cfg, params, prompts)`` for a parsed command line: the
+    :func:`serving_config`, weights from ``--seed`` — PMQ-compressed when
+    ``--pmq`` or ``--resident-experts`` asks — and ``--requests`` seeded
+    prompts."""
+    _check_args(args)
+    if args.ffn_backend:
+        # process default too, so the --legacy wave batcher (no engine
+        # config, plain decode_step) honors the same A/B knob
+        import os
+
+        os.environ["REPRO_FFN_BACKEND"] = args.ffn_backend
+    cfg = serving_config(args)
+    params = get_model(cfg).init(jax.random.PRNGKey(args.seed))
     if args.pmq or args.resident_experts is not None:
         if not cfg.is_moe:
             flag = "--pmq" if args.pmq else "--resident-experts"
             raise SystemExit(f"{flag} requires an MoE arch")
         print("compressing demo model (PMQ, layer-uniform plan)…")
-        params = _compress_for_serving(cfg, params)
-    rng = np.random.default_rng(0)
+        params = _compress_for_serving(cfg, params, args.seed)
+    rng = np.random.default_rng(args.seed)
     prompts = [
-        rng.integers(0, cfg.vocab_size, size=24).astype(np.int32)
+        rng.integers(0, cfg.vocab_size, size=args.prompt_len).astype(np.int32)
         for _ in range(args.requests)
     ]
-    if args.legacy:
-        server = BatchedServer(cfg, params, max_slots=args.slots)
-        reqs = [
-            Request(rid=i, prompt=prompts[i], max_new=args.max_new)
-            for i in range(args.requests)
-        ]
-        out = server.serve(reqs)
-        print(f"served {len(out)} requests; stats: {server.summary()}")
-        return
-    blocks_per_req = (24 + args.max_new) // args.block_size + 2
+    return cfg, params, prompts
+
+
+def build_engine(args, cfg, params, *, keep_logits: bool = False
+                 ) -> PagedServingEngine:
+    """The paged engine a parsed command line describes, over ``params``;
+    the pool holds every request's worst case unless ``--pool-blocks``
+    undersizes it. ``keep_logits`` records every served token's logits
+    (``EngineConfig.keep_logits``)."""
+    tenant_weights = (
+        _parse_tenant_weights(args.tenant_weights)
+        if args.tenant_weights else None
+    )
+    trace_level = args.trace_level or ("full" if args.trace_out else "off")
+    blocks_per_req = (args.prompt_len + args.max_new) // args.block_size + 2
     plan = None
     if args.chaos_seed is not None:
         from ..serving import FaultPlan
@@ -416,6 +456,7 @@ def main() -> None:
             trace_level=trace_level,
             prefix_cache=args.prefix_cache,
             kv_bits=args.kv_bits,
+            keep_logits=keep_logits,
             policy=args.policy or "fcfs",
             tenant_weights=tenant_weights,
             ttft_budget_s=(
@@ -427,11 +468,29 @@ def main() -> None:
         ),
         faults=plan,
     )
-    if engine.offload is not None:
-        # the engine's tree holds the resident partition + host store;
-        # dropping the caller's reference releases the full-resident
-        # device buckets — the memory the budget exists to reclaim
-        del params
+    return engine
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    args = parser().parse_args(argv)
+    cfg, params, prompts = build_model(args)
+    if args.legacy:
+        server = BatchedServer(cfg, params, max_slots=args.slots,
+                               prompt_len=args.prompt_len)
+        reqs = [
+            Request(rid=i, prompt=prompts[i], max_new=args.max_new)
+            for i in range(args.requests)
+        ]
+        out = server.serve(reqs)
+        print(f"served {len(out)} requests; stats: {server.summary()}")
+        return
+    engine = build_engine(args, cfg, params)
+    # the engine's tree holds any resident partition + host store;
+    # dropping the caller's reference releases the full-resident device
+    # buckets — the memory an offload budget exists to reclaim
+    del params
+    tenant_weights = engine.ecfg.tenant_weights
     tenant_names = (
         [t for t, _ in tenant_weights] if tenant_weights else ["default"]
     )
@@ -482,7 +541,7 @@ def main() -> None:
                 f"{m['tier_disk_hits']} disk fetches "
                 f"({m['tier_disk_bytes']} B, CRC-checked)"
             )
-    if plan is not None or args.deadline_steps is not None:
+    if engine.faults is not None or args.deadline_steps is not None:
         ctr = engine.metrics.counters()
         print(
             f"fault plane: {ctr['fault_injected']} injected "

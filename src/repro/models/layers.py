@@ -86,9 +86,8 @@ def embed_tokens(embed: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
             return jax.lax.psum(x, "model")
 
     from jax.sharding import PartitionSpec as P
-    from ..parallel.sharding import shard_map
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("model", None), P(ba, None)),

@@ -547,7 +547,7 @@ def paged_decode_horizon(params, cache, token: jnp.ndarray,
                          positions: jnp.ndarray, cfg, *, horizon: int,
                          budgets: jnp.ndarray, eos_ids: jnp.ndarray,
                          moe_hooks=None, temperature: float = 0.0,
-                         rng_key=None):
+                         rng_key=None, keep_logits: bool = False):
     """Fused ``H``-step decode: one jitted program advances every slot up
     to ``horizon`` tokens with **on-device sampling** feeding each step's
     output token into the next step — the serving engine pays one
@@ -580,6 +580,8 @@ def paged_decode_horizon(params, cache, token: jnp.ndarray,
     active-masked activation ``[H]`` and ``info["slot_counts"]`` the
     per-step dispatch counts ``[H, L, num_slots]`` (step-major — the
     offload manager's horizon-union working set + replay order).
+    ``keep_logits`` (trace-time static) adds ``info["logits"]``, the
+    ``[H, B, V]`` float32 logits each step sampled from.
     """
     if horizon < 1:
         raise ValueError(f"horizon must be ≥ 1, got {horizon}")
@@ -614,6 +616,7 @@ def paged_decode_horizon(params, cache, token: jnp.ndarray,
             emit,
             _masked_activation(per_slot, act),
             counts,
+            *([lg] if keep_logits else []),
         )
         carry = (kf, vf, qs, nxt[:, None], pos + emit.astype(jnp.int32),
                  act & ~stop, budget)
@@ -634,7 +637,7 @@ def paged_decode_horizon(params, cache, token: jnp.ndarray,
     # fusing across steps (measured ~1.8x per-step decode cost on CPU);
     # unrolling keeps per-step cost at the single-step program's while
     # still eliminating the per-token host round-trips
-    (kf, vf, qs, *_), (toks, emits, acts, counts) = jax.lax.scan(
+    (kf, vf, qs, *_), (toks, emits, acts, counts, *logits) = jax.lax.scan(
         step, init, keys, unroll=horizon
     )
     new_cache = dict(
@@ -645,6 +648,8 @@ def paged_decode_horizon(params, cache, token: jnp.ndarray,
     if qs:
         new_cache["kv_quant"] = _unflatten_kv_quant(qs, nl, nb, bs, hkv)
     info = {"expert_activation": acts, "slot_counts": counts}
+    if keep_logits:
+        info["logits"] = logits[0]
     return new_cache, toks, emits, info
 
 
@@ -767,10 +772,18 @@ def forward_layers_python(params, tokens, cfg, *, capture: str = "moe"):
     raise NotImplementedError("use repro.core.calibrate helpers")
 
 
+def iter_blocks(params, cfg):
+    """Yield each layer's block pytree in turn. A layer's slice is made
+    only when it is reached, so a loop that drops it before the next
+    layer holds one layer's copy at a time, not the whole stack's."""
+    blocks = params["blocks"]
+    for i in range(cfg.num_layers):
+        yield jax.tree.map(lambda a: a[i], blocks)
+
+
 def unstack_blocks(params, cfg):
     """Split stacked block params into a list of per-layer pytrees."""
-    blocks = params["blocks"]
-    return [jax.tree.map(lambda a: a[i], blocks) for i in range(cfg.num_layers)]
+    return list(iter_blocks(params, cfg))
 
 
 def restack_blocks(block_list):

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from . import sharding as shd
 from .sharding import batch_axes, manual_region
 
 __all__ = ["moe_region_sharded", "compressed_moe_region_sharded"]
@@ -78,7 +77,7 @@ def moe_region_sharded(p: Dict, x: jnp.ndarray, cfg, mesh,
         aux = jax.lax.pmean(moe_mod.load_balance_loss(probs, idx, e), ba)
         return y.reshape(b, s, d), aux
 
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -319,7 +318,7 @@ def compressed_moe_region_sharded(
     x_spec = (
         P(None, None, None) if etp_mode == "replicate_tokens" else P(ba, None, None)
     )
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), *otp_specs, *spec_list),

@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "shard",
-    "shard_map",
     "sharding_rules",
     "batch_axes",
     "activation_rules",
@@ -28,22 +27,6 @@ __all__ = [
     "cache_pspec",
 ]
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version shim: ``jax.shard_map`` (new API) or
-    ``jax.experimental.shard_map.shard_map`` (jax ≤ 0.4.x, where the
-    replication check is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar("sharding_ctx", default=None)
 

@@ -249,6 +249,13 @@ class EngineConfig:
     # from sample_seed so runs (and offload replays) are deterministic.
     temperature: float = 0.0
     sample_seed: int = 0
+    # Keep the float32 logits behind every emitted token in
+    # ``engine.token_logits[rid]``: the prefill's first-token row, then
+    # one row per decode step — what a check of served logits against a
+    # reference reads. Trace-time static: the decode megastep then also
+    # returns its [H, slots, V] logits, one more device-to-host copy per
+    # megastep. Off for serving.
+    keep_logits: bool = False
     # Shared-prefix KV reuse: admission probes a prefix → physical-page-
     # run cache (exact token keys, LRU) and shares matching page-aligned
     # pages copy-on-write instead of re-prefilling them; fresh prompts
@@ -315,7 +322,8 @@ class EngineConfig:
 
 @functools.lru_cache(maxsize=None)
 def _jitted_steps(model_cfg, use_otp: bool, ffn_backend: Optional[str] = None,
-                  horizon: int = 1, temperature: float = 0.0):
+                  horizon: int = 1, temperature: float = 0.0,
+                  keep_logits: bool = False):
     """Compiled decode-megastep/prefill builders, shared across engines
     with the same (hashable, frozen) model config and the same static
     horizon/sampling knobs — jit caching then dedupes by array shapes
@@ -327,7 +335,8 @@ def _jitted_steps(model_cfg, use_otp: bool, ffn_backend: Optional[str] = None,
     after the pools (``None`` on fp engines — an empty pytree that
     donates and returns as nothing): the tables are pool metadata and
     must travel through every donated round-trip with the codes they
-    dequantize.
+    dequantize. ``keep_logits`` adds the megastep's ``[H, B, V]`` logits
+    to the decode outputs, just before the dispatch counts.
     """
     hooks = {"use_otp": use_otp, "ffn_backend": ffn_backend}
 
@@ -339,11 +348,12 @@ def _jitted_steps(model_cfg, use_otp: bool, ffn_backend: Optional[str] = None,
         new_cache, toks, emits, info = tf.paged_decode_horizon(
             params, cache, token, positions, model_cfg, horizon=horizon,
             budgets=budgets, eos_ids=eos_ids, moe_hooks=hooks,
-            temperature=temperature, rng_key=key,
+            temperature=temperature, rng_key=key, keep_logits=keep_logits,
         )
         return (
             new_cache["k"], new_cache["v"], new_cache.get("kv_quant"),
-            toks, emits, info["expert_activation"], info["slot_counts"],
+            toks, emits, info["expert_activation"],
+            *([info["logits"]] if keep_logits else []), info["slot_counts"],
         )
 
     def prefill_fn(params, k, v, quant, tokens, start, valid_len, table_row):
@@ -540,7 +550,10 @@ class PagedServingEngine:
         self._decode, self._prefill = _jitted_steps(
             self.model_cfg, self.ecfg.use_otp, self.ecfg.ffn_backend,
             self.ecfg.decode_horizon, float(self.ecfg.temperature),
+            self.ecfg.keep_logits,
         )
+        # rid → [V] float32 logits per emitted token (keep_logits only)
+        self.token_logits: Dict[int, List[np.ndarray]] = {}
 
     # ----------------------------------------------------- observability
     def routing_report(self) -> Optional[Dict]:
@@ -1006,6 +1019,8 @@ class PagedServingEngine:
                 rid=req.rid,
             )
         self.cache.register_prefix(req.prompt, req.slot, last_logits=last)
+        if self.ecfg.keep_logits:
+            self.token_logits[req.rid] = [np.asarray(last, np.float32)]
         if self.ecfg.temperature > 0.0:
             # the TTFT token is sampled too — same categorical draw the
             # horizon scan applies to every later token
@@ -1200,15 +1215,20 @@ class PagedServingEngine:
             self.cache.grow(slot, need)
 
     # ------------------------------------------------------------ decode
-    def _decode_megastep(self) -> None:
-        """Advance every active slot up to ``decode_horizon`` tokens in
-        one fused jitted program, then apply the fetched ``[H, slots]``
-        token matrix host-side: one dispatch, one host sync, one Python
-        pass per megastep. Per-logical-step metrics are reconstructed
-        from the emit mask (exact) and the megastep wall time (spread
-        evenly — see serving.metrics)."""
+    def lower_decode(self):
+        """The decode megastep the server runs, lowered at this engine's
+        shapes without running it: ``.compile().as_text()`` is the device
+        program (on TPU its Pallas kernels appear as ``tpu_custom_call``)
+        and ``.compile().memory_analysis()`` its buffer sizes."""
+        args, _ = self._decode_args()
+        return self._decode.lower(
+            self.params, self.cache.k, self.cache.v, self.cache.quant, *args
+        )
+
+    def _decode_args(self):
+        """Host-built inputs of one decode megastep (everything after the
+        pools) and the ``[slots]`` active mask."""
         b = self.ecfg.max_slots
-        h = self.ecfg.decode_horizon
         tokens = np.zeros((b, 1), np.int32)
         positions = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)
@@ -1225,17 +1245,30 @@ class PagedServingEngine:
         key = None
         if self.ecfg.temperature > 0.0:
             key = jax.random.fold_in(self._sample_key, self._megastep_idx)
+        args = (jnp.asarray(tokens), jnp.asarray(positions),
+                self.cache.tables_device(), jnp.asarray(active),
+                jnp.asarray(budgets), jnp.asarray(eos_ids), key)
+        return args, active
+
+    def _decode_megastep(self) -> None:
+        """Advance every active slot up to ``decode_horizon`` tokens in
+        one fused jitted program, then apply the fetched ``[H, slots]``
+        token matrix host-side: one dispatch, one host sync, one Python
+        pass per megastep. Per-logical-step metrics are reconstructed
+        from the emit mask (exact) and the megastep wall time (spread
+        evenly — see serving.metrics)."""
+        b = self.ecfg.max_slots
+        h = self.ecfg.decode_horizon
+        args, active = self._decode_args()
         t0 = time.time()
         t0_us = self.tracer.now_us()
-        toks, emits, acts, counts = self._run_offloaded(
-            self._decode,
-            (jnp.asarray(tokens), jnp.asarray(positions),
-             self.cache.tables_device(), jnp.asarray(active),
-             jnp.asarray(budgets), jnp.asarray(eos_ids), key),
+        toks, emits, acts, *kept, counts = self._run_offloaded(
+            self._decode, args
         )
         toks = np.asarray(toks)          # [H, B] (-1 where not emitted)
         emits = np.asarray(emits)        # [H, B] bool
         acts = np.asarray(acts)          # [H]
+        logits = np.asarray(kept[0]) if kept else None  # [H, B, V]
         dt = time.time() - t0
         stats = self._last_run_stats
         # logical steps that emitted ≥ 1 token; trailing all-stopped scan
@@ -1285,6 +1318,8 @@ class PagedServingEngine:
                 if emits[s, slot]:
                     req.out.append(int(toks[s, slot]))
                     req.pos += 1
+                    if logits is not None:
+                        self.token_logits[req.rid].append(logits[s, slot])
                     last_s = s
                     emitted += 1
             # fairness accounting: debit the tenant's WDRR grant and

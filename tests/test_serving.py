@@ -423,6 +423,30 @@ def test_engine_serve_matches_dense_greedy_reference(model):
     assert out[0] == ref_toks
 
 
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_engine_keep_logits_matches_dense_reference(model, horizon):
+    """``keep_logits`` records the logits behind every served token — the
+    prefill's first, then each decode step's — equal to the dense
+    reference's, and leaves the tokens unchanged."""
+    cfg, params = model
+    eng = PagedServingEngine(cfg, params, dataclasses.replace(
+        ECFG, decode_horizon=horizon, keep_logits=True))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (7, 5)]
+    out = eng.serve([Request(rid=i, prompt=p, max_new=6)
+                     for i, p in enumerate(prompts)])
+    for rid, prompt in enumerate(prompts):
+        ref_toks, ref_logits = dense_greedy_reference(
+            eng.model_cfg, params, prompt, 6
+        )
+        assert out[rid] == ref_toks
+        kept = np.stack(eng.token_logits[rid])
+        assert kept.shape == (6, cfg.vocab_size)
+        np.testing.assert_allclose(kept, np.stack(ref_logits[:6]),
+                                   rtol=1e-4, atol=1e-4)
+
+
 # -------------------------------------------------- continuous batching
 def test_scheduler_mid_flight_admission(model):
     """With 2 slots and 3 requests, the third must join once a short
