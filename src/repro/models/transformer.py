@@ -431,50 +431,53 @@ def _paged_decode_core(params, kf, vf, qs, tables, token, positions, active,
     def body(carry, xs):
         xc, kf, vf, qs = carry
         p_l, win, l = xs
-        h = L.rms_norm(xc, p_l["ln1"], cfg.norm_eps)
-        q, k_new, v_new = L._qkv(p_l["attn"], h, cfg, positions[:, None])
-        quant_l = None
-        if quantized:
-            ksf, kzf, vsf, vzf = qs
-            kc, ks, kz = quantize_kv_rows(k_new[:, 0], KV_QUANT_BITS)
-            vc, vs, vz = quantize_kv_rows(v_new[:, 0], KV_QUANT_BITS)
-            kf = kf.at[l, dest].set(kc, mode="drop")
-            vf = vf.at[l, dest].set(vc, mode="drop")
-            ksf = ksf.at[l, dest].set(ks, mode="drop")
-            kzf = kzf.at[l, dest].set(kz, mode="drop")
-            vsf = vsf.at[l, dest].set(vs, mode="drop")
-            vzf = vzf.at[l, dest].set(vz, mode="drop")
-            qs = (ksf, kzf, vsf, vzf)
-            quant_l = tuple(
-                jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-                .reshape(nb, bs, hkv) for a in qs
+        with jax.named_scope("attention"):
+            h = L.rms_norm(xc, p_l["ln1"], cfg.norm_eps)
+            q, k_new, v_new = L._qkv(p_l["attn"], h, cfg, positions[:, None])
+            quant_l = None
+            if quantized:
+                ksf, kzf, vsf, vzf = qs
+                kc, ks, kz = quantize_kv_rows(k_new[:, 0], KV_QUANT_BITS)
+                vc, vs, vz = quantize_kv_rows(v_new[:, 0], KV_QUANT_BITS)
+                kf = kf.at[l, dest].set(kc, mode="drop")
+                vf = vf.at[l, dest].set(vc, mode="drop")
+                ksf = ksf.at[l, dest].set(ks, mode="drop")
+                kzf = kzf.at[l, dest].set(kz, mode="drop")
+                vsf = vsf.at[l, dest].set(vs, mode="drop")
+                vzf = vzf.at[l, dest].set(vz, mode="drop")
+                qs = (ksf, kzf, vsf, vzf)
+                quant_l = tuple(
+                    jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+                    .reshape(nb, bs, hkv) for a in qs
+                )
+            else:
+                kf = kf.at[l, dest].set(k_new[:, 0].astype(kf.dtype), mode="drop")
+                vf = vf.at[l, dest].set(v_new[:, 0].astype(vf.dtype), mode="drop")
+            k_l = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)
+            v_l = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)
+            attn = ops.paged_attention(
+                q.reshape(b, hkv, g, dh),
+                k_l.reshape(nb, bs, hkv, dh),
+                v_l.reshape(nb, bs, hkv, dh),
+                tables, lengths, window=win, quant=quant_l,
             )
-        else:
-            kf = kf.at[l, dest].set(k_new[:, 0].astype(kf.dtype), mode="drop")
-            vf = vf.at[l, dest].set(v_new[:, 0].astype(vf.dtype), mode="drop")
-        k_l = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)
-        attn = ops.paged_attention(
-            q.reshape(b, hkv, g, dh),
-            k_l.reshape(nb, bs, hkv, dh),
-            v_l.reshape(nb, bs, hkv, dh),
-            tables, lengths, window=win, quant=quant_l,
-        )
-        attn = attn.reshape(b, 1, hq * dh).astype(xc.dtype)
-        xc = xc + L.linear(p_l["attn"]["wo"], attn)
-        h2 = L.rms_norm(xc, p_l["ln2"], cfg.norm_eps)
-        delta, act, counts = _ffn_delta(p_l, h2, cfg, hooks)
-        xc = xc + delta
+            attn = attn.reshape(b, 1, hq * dh).astype(xc.dtype)
+            xc = xc + L.linear(p_l["attn"]["wo"], attn)
+        with jax.named_scope("moe"):
+            h2 = L.rms_norm(xc, p_l["ln2"], cfg.norm_eps)
+            delta, act, counts = _ffn_delta(p_l, h2, cfg, hooks)
+            xc = xc + delta
         return (xc, kf, vf, qs), (act, counts)
 
     (x, kf, vf, qs), (acts, slot_counts) = jax.lax.scan(
         body, (x, kf, vf, qs), (params["blocks"], windows, layer_ids)
     )
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "btd,vd->btv", x.astype(jnp.float32),
-        _out_embedding(params).astype(jnp.float32),
-    )
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum(
+            "btd,vd->btv", x.astype(jnp.float32),
+            _out_embedding(params).astype(jnp.float32),
+        )
     # acts [L, B, 1] per-token: keep per-slot so garbage tokens decoded
     # by empty slots cannot dilute the OTP activation metric
     per_slot = acts.mean(axis=(0, 2))  # [B]
@@ -703,55 +706,58 @@ def paged_prefill_chunk(params, cache, tokens: jnp.ndarray, start: jnp.ndarray,
     def body(carry, xs):
         xc, kf, vf, qs = carry
         p_l, win, l = xs
-        h = L.rms_norm(xc, p_l["ln1"], cfg.norm_eps)
-        k_new, v_new = L._kv_only(p_l["attn"], h, cfg, pos2d)
-        if quantized:
-            ksf, kzf, vsf, vzf = qs
-            kc, ks, kz = quantize_kv_rows(k_new[0], KV_QUANT_BITS)
-            vc, vs, vz = quantize_kv_rows(v_new[0], KV_QUANT_BITS)
-            kf = kf.at[l, dest].set(kc, mode="drop")
-            vf = vf.at[l, dest].set(vc, mode="drop")
-            ksf = ksf.at[l, dest].set(ks, mode="drop")
-            kzf = kzf.at[l, dest].set(kz, mode="drop")
-            vsf = vsf.at[l, dest].set(vs, mode="drop")
-            vzf = vzf.at[l, dest].set(vz, mode="drop")
-            qs = (ksf, kzf, vsf, vzf)
-            # dequantize the gathered rows with the SAME f32 expression as
-            # the paged-attention kernels' epilogue — prefill attention
-            # over shared-prefix pages sees bit-identical floats to every
-            # later decode read of the same pages
-            ksl, kzl, vsl, vzl = (
-                jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)[phys]
-                for a in qs
+        with jax.named_scope("attention"):
+            h = L.rms_norm(xc, p_l["ln1"], cfg.norm_eps)
+            k_new, v_new = L._kv_only(p_l["attn"], h, cfg, pos2d)
+            if quantized:
+                ksf, kzf, vsf, vzf = qs
+                kc, ks, kz = quantize_kv_rows(k_new[0], KV_QUANT_BITS)
+                vc, vs, vz = quantize_kv_rows(v_new[0], KV_QUANT_BITS)
+                kf = kf.at[l, dest].set(kc, mode="drop")
+                vf = vf.at[l, dest].set(vc, mode="drop")
+                ksf = ksf.at[l, dest].set(ks, mode="drop")
+                kzf = kzf.at[l, dest].set(kz, mode="drop")
+                vsf = vsf.at[l, dest].set(vs, mode="drop")
+                vzf = vzf.at[l, dest].set(vz, mode="drop")
+                qs = (ksf, kzf, vsf, vzf)
+                # dequantize the gathered rows with the SAME f32 expression as
+                # the paged-attention kernels' epilogue — prefill attention
+                # over shared-prefix pages sees bit-identical floats to every
+                # later decode read of the same pages
+                ksl, kzl, vsl, vzl = (
+                    jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)[phys]
+                    for a in qs
+                )
+                kr = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)[phys]
+                vr = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)[phys]
+                k_log = dequantize_kv_rows(kr, ksl, kzl)[None]
+                v_log = dequantize_kv_rows(vr, vsl, vzl)[None]
+            else:
+                kf = kf.at[l, dest].set(k_new[0].astype(kf.dtype), mode="drop")
+                vf = vf.at[l, dest].set(v_new[0].astype(vf.dtype), mode="drop")
+                k_log = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)[phys][None]
+                v_log = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)[phys][None]
+            attn_out, _ = L.attention(
+                p_l["attn"], h, cfg, positions=pos2d, causal=True, window=win,
+                kv_override=(k_log, v_log, kv_pos),
             )
-            kr = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)[phys]
-            vr = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)[phys]
-            k_log = dequantize_kv_rows(kr, ksl, kzl)[None]
-            v_log = dequantize_kv_rows(vr, vsl, vzl)[None]
-        else:
-            kf = kf.at[l, dest].set(k_new[0].astype(kf.dtype), mode="drop")
-            vf = vf.at[l, dest].set(v_new[0].astype(vf.dtype), mode="drop")
-            k_log = jax.lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)[phys][None]
-            v_log = jax.lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)[phys][None]
-        attn_out, _ = L.attention(
-            p_l["attn"], h, cfg, positions=pos2d, causal=True, window=win,
-            kv_override=(k_log, v_log, kv_pos),
-        )
-        xc = xc + attn_out
-        h2 = L.rms_norm(xc, p_l["ln2"], cfg.norm_eps)
-        delta, _, counts = _ffn_delta(p_l, h2, cfg, hooks)
-        xc = xc + delta
+            xc = xc + attn_out
+        with jax.named_scope("moe"):
+            h2 = L.rms_norm(xc, p_l["ln2"], cfg.norm_eps)
+            delta, _, counts = _ffn_delta(p_l, h2, cfg, hooks)
+            xc = xc + delta
         return (xc, kf, vf, qs), counts
 
     (x, kf, vf, qs), slot_counts = jax.lax.scan(
         body, (x, kf, vf, qs), (params["blocks"], windows, layer_ids)
     )
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
-    logits = jnp.einsum(
-        "btd,vd->btv", last.astype(jnp.float32),
-        _out_embedding(params).astype(jnp.float32),
-    )
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
+        logits = jnp.einsum(
+            "btd,vd->btv", last.astype(jnp.float32),
+            _out_embedding(params).astype(jnp.float32),
+        )
     new_cache = dict(
         cache,
         k=kf.reshape(nl, nb, bs, hkv, dh),

@@ -625,18 +625,21 @@ class PagedServingEngine:
         fail the whole engine closed (:meth:`_fail_closed`) rather than
         hang or serve silently corrupted state.
         """
-        if self.faults is not None:
-            self.faults.at_step(self._step_idx)
-        self._apply_cancellations()
-        self._apply_deadlines()
-        if not self.scheduler.has_work():
-            return False
-        progress0 = (
-            self._step_idx,
-            sum(len(v) for v in self.results.values()),
-        )
-        try:
-            self._converge()
+        with self.tracer.span("boundary", track="engine", cat="engine"):
+            if self.faults is not None:
+                self.faults.at_step(self._step_idx)
+            self._apply_cancellations()
+            self._apply_deadlines()
+            if not self.scheduler.has_work():
+                return False
+            progress0 = (
+                self._step_idx,
+                sum(len(v) for v in self.results.values()),
+            )
+            try:
+                self._converge()
+            except ExpertUploadFailed as exc:
+                self._fail_closed(exc)
             if not self.scheduler.active:
                 if not self.scheduler.waiting:
                     return False
@@ -666,7 +669,8 @@ class PagedServingEngine:
                 # just-cleared cache), but fall through to the no-progress
                 # accounting — a *persistent* stall must eventually fail
                 # closed as a livelock, not spin forever
-            else:
+        if self.scheduler.active:
+            try:
                 t_start = self._clock()
                 if self._watchdog is not None:
                     self._watchdog.beat("megastep", now=t_start)
@@ -678,8 +682,8 @@ class PagedServingEngine:
                         f"megastep exceeded the "
                         f"{self.ecfg.watchdog_timeout_s}s watchdog budget"
                     )
-        except (ExpertUploadFailed, WatchdogTimeout) as exc:
-            self._fail_closed(exc)
+            except (ExpertUploadFailed, WatchdogTimeout) as exc:
+                self._fail_closed(exc)
         progress1 = (
             self._step_idx,
             sum(len(v) for v in self.results.values()),
@@ -783,7 +787,8 @@ class PagedServingEngine:
                 self.metrics.record_async_commit(
                     committed, dropped, nbytes, wait_s
                 )
-        plan = self.controller.plan_boundary(self._step_idx, time.time())
+        with self.tracer.span("plan", track="engine", cat="engine"):
+            plan = self.controller.plan_boundary(self._step_idx, time.time())
         self._execute_plan(plan)
 
     def _execute_plan(self, plan: List[PlanAction]) -> None:
@@ -957,48 +962,69 @@ class PagedServingEngine:
             assert len(seq) == req.pos, (len(seq), req.pos)
         else:
             seq = req.prompt
+        track = f"slot{req.slot}"
+        with self.tracer.span("prefill", track=track, cat="prefill",
+                              rid=req.rid, resume=resume):
+            logits = self._prefill_chunks(req, seq, resume, track)
+            if resume:
+                return
+            with self.tracer.span("sample", track=track, cat="prefill"):
+                self._first_token(req, logits, len(seq), track)
+
+    def _prefill_chunks(self, req: Request, seq: np.ndarray, resume: bool,
+                        track: str):
+        """Run ``seq``'s chunks from the first token the slot does not
+        hold yet; returns the last chunk's logits (``None`` when a full
+        prefix hit ran no chunk)."""
         p_len = len(seq)
         c = self.ecfg.prefill_chunk
-        track = f"slot{req.slot}"
         off0 = 0 if resume else min(req.cached_tokens, p_len)
         if not resume and req.cached_logits is not None and off0 >= p_len:
-            last = np.asarray(req.cached_logits)
-        else:
-            assert off0 < p_len, (off0, p_len)  # scheduler demotes no-logits full hits
+            return None
+        assert off0 < p_len, (off0, p_len)  # scheduler demotes no-logits full hits
+        with self.tracer.span("inputs", track=track, cat="prefill"):
             table_row = jnp.asarray(
                 self.cache.block_tables[req.slot : req.slot + 1]
             )
-            logits = None
-            for off in range(off0, p_len, c):
-                if req.rid in self._cancel_requests:
-                    # mid-prefill cancellation: stop streaming chunks
-                    # now; the caller releases the slot (and any KV
-                    # already written dies with the pages)
-                    raise RequestCancelled(
-                        f"request {req.rid} cancelled mid-prefill",
-                        rid=req.rid,
-                    )
-                n = min(c, p_len - off)
-                chunk = np.zeros((1, c), np.int32)
-                chunk[0, :n] = seq[off : off + n]
-                args = (
-                    jnp.asarray(chunk), jnp.int32(off), jnp.int32(n),
-                    table_row,
+        logits = None
+        for off in range(off0, p_len, c):
+            if req.rid in self._cancel_requests:
+                # mid-prefill cancellation: stop streaming chunks
+                # now; the caller releases the slot (and any KV
+                # already written dies with the pages)
+                raise RequestCancelled(
+                    f"request {req.rid} cancelled mid-prefill",
+                    rid=req.rid,
                 )
-                t0 = self.tracer.now_us()
+            n = min(c, p_len - off)
+            with self.tracer.span(
+                "prefill_chunk", track=track, cat="prefill", rid=req.rid,
+                offset=off, tokens=n, resume=resume,
+            ) as chunk_span:
+                with self.tracer.span("inputs", track=track, cat="prefill"):
+                    chunk = np.zeros((1, c), np.int32)
+                    chunk[0, :n] = seq[off : off + n]
+                    args = (
+                        jnp.asarray(chunk), jnp.int32(off), jnp.int32(n),
+                        table_row,
+                    )
                 logits, counts = self._run_offloaded(
                     self._prefill, args, kind="prefill", track=track
                 )
-                self.metrics.record_prefill_runs(self._last_run_stats["runs"])
-                self.tracer.complete(
-                    "prefill_chunk", track=track, cat="prefill", start_us=t0,
-                    args={"rid": req.rid, "offset": off, "tokens": n,
-                          "resume": resume,
-                          "runs": int(self._last_run_stats["runs"])},
-                )
-                self._record_capacity_util(counts, c)
-            if resume:
-                return
+                runs = self._last_run_stats["runs"]
+                chunk_span.args["runs"] = int(runs)
+                with self.tracer.span("account", track=track, cat="prefill"):
+                    self.metrics.record_prefill_runs(runs)
+                    self._record_capacity_util(counts, c)
+        return logits
+
+    def _first_token(self, req: Request, logits, p_len: int,
+                     track: str) -> None:
+        """Fetch the prompt's last logits (or the prefix cache's), guard
+        them, register the prompt's prefixes and emit the first token."""
+        if logits is None:
+            last = np.asarray(req.cached_logits)
+        else:
             jax.block_until_ready(logits)
             last = np.asarray(logits)[0, -1]
         if self.faults is not None:
@@ -1053,10 +1079,10 @@ class PagedServingEngine:
         emitted after the donated pools, with the trailing dispatch
         counts already fetched to host numpy (this fetch is the
         megastep's one host sync). ``self._last_run_stats`` records the
-        run count and the compute/offload wall-time split: the first run
-        is pure decode/prefill math, everything after it (uploads +
-        replays) is offload overhead that used to conflate into the
-        latency metric.
+        run count and the compute/offload split, timed by the spans: the
+        first run (``compute``) is pure decode/prefill math, everything
+        after it (``residency`` checks and uploads, ``replay`` runs) is
+        offload overhead that used to conflate into the latency metric.
         """
         if self.offload is not None:
             self.offload.begin_step()
@@ -1065,66 +1091,69 @@ class PagedServingEngine:
         compute_s = 0.0
         offload_s = 0.0
         while True:
-            t0 = time.time()
-            t0_us = self.tracer.now_us()
-            out = program(
-                self.params, self.cache.k, self.cache.v, self.cache.quant,
-                *args,
-            )
-            self.cache.k, self.cache.v = out[0], out[1]
-            if out[2] is not None:  # quantized pools: scale/zero tables
-                self.cache.quant = out[2]
-            payload = out[3:-1]
-            if runs == 0 and self._pending_expert_targets:
-                # async expert streaming: the program is dispatched but
-                # its counts not yet fetched — stage the boundary's
-                # prefetch uploads now so the copies land while it
-                # computes; the flip happens at the next boundary
-                targets = self._pending_expert_targets
-                self._pending_expert_targets = ()
-                ti = time.time()
-                ups, _ = self.offload.issue_async(targets)
-                if ups:
-                    self.metrics.record_async_issue(ups, time.time() - ti)
-            # the one host sync: dispatch counts ([L, num_slots] for a
-            # prefill chunk, [H, L, num_slots] for a decode megastep;
-            # trailing dim 0 outside PMQ) — fetched for the offload miss
-            # check and the capacity-utilization gauge
-            counts = np.asarray(out[-1])
-            runs += 1
-            dt = time.time() - t0
             # run 1 is the program's real math; every later run is a
             # miss replay — the compute-vs-offload split, visible per run
-            self.tracer.complete(
-                "compute" if runs == 1 else "replay", track=track,
-                cat=kind, start_us=t0_us, args={"run": runs},
-            )
+            with self.tracer.span(
+                "compute" if runs == 0 else "replay", track=track,
+                cat=kind, run=runs + 1,
+            ) as run_span:
+                with self.tracer.span("dispatch", track=track, cat=kind):
+                    out = program(
+                        self.params, self.cache.k, self.cache.v,
+                        self.cache.quant, *args,
+                    )
+                self.cache.k, self.cache.v = out[0], out[1]
+                if out[2] is not None:  # quantized pools: scale/zero tables
+                    self.cache.quant = out[2]
+                payload = out[3:-1]
+                if runs == 0 and self._pending_expert_targets:
+                    # async expert streaming: the program is dispatched
+                    # but its counts not yet fetched — stage the
+                    # boundary's prefetch uploads now so the copies land
+                    # while it computes; the flip happens at the next
+                    # boundary
+                    targets = self._pending_expert_targets
+                    self._pending_expert_targets = ()
+                    with self.tracer.span("issue", track=track,
+                                          cat="offload") as issue:
+                        ups, _ = self.offload.issue_async(targets)
+                    if ups:
+                        self.metrics.record_async_issue(ups, issue.seconds)
+                # the one host sync: dispatch counts ([L, num_slots] for
+                # a prefill chunk, [H, L, num_slots] for a decode
+                # megastep; trailing dim 0 outside PMQ) — fetched for the
+                # offload miss check and the capacity-utilization gauge
+                with self.tracer.span("sync", track=track, cat=kind):
+                    counts = np.asarray(out[-1])
+                runs += 1
             if runs == 1:
-                compute_s = dt
+                compute_s = run_span.seconds
             else:
-                offload_s += dt
+                offload_s += run_span.seconds
             if self.offload is None:
                 self._last_run_stats = {
                     "runs": runs, "compute_s": compute_s,
                     "offload_s": offload_s,
                 }
                 return payload + (counts,)
-            t1 = time.time()
-            # ensure_resident normalizes [L,S] and [H,L,S] itself
-            uploads, nbytes = self.offload.ensure_resident(counts)
+            with self.tracer.span("residency", track=track,
+                                  cat="offload") as check:
+                # ensure_resident normalizes [L,S] and [H,L,S] itself
+                uploads, nbytes = self.offload.ensure_resident(counts)
+                if uploads == 0:
+                    if missed:
+                        self.metrics.record_expert_miss_step()
+                    else:
+                        self.metrics.record_expert_hit()
+                    self.offload.update_stats(counts)
+            offload_s += check.seconds
             if uploads == 0:
-                if missed:
-                    self.metrics.record_expert_miss_step()
-                else:
-                    self.metrics.record_expert_hit()
-                self.offload.update_stats(counts)
                 self._last_run_stats = {
                     "runs": runs, "compute_s": compute_s,
-                    "offload_s": offload_s + (time.time() - t1),
+                    "offload_s": offload_s,
                 }
                 return payload + (counts,)
             missed = True
-            offload_s += time.time() - t1
             self.metrics.record_expert_miss(uploads, nbytes)
 
     def _record_capacity_util(self, counts: np.ndarray, t: int) -> None:
@@ -1255,84 +1284,98 @@ class PagedServingEngine:
         one fused jitted program, then apply the fetched ``[H, slots]``
         token matrix host-side: one dispatch, one host sync, one Python
         pass per megastep. Per-logical-step metrics are reconstructed
-        from the emit mask (exact) and the megastep wall time (spread
-        evenly — see serving.metrics)."""
+        from the emit mask (exact) and the megastep's run and fetch time
+        (spread evenly — see serving.metrics)."""
         b = self.ecfg.max_slots
         h = self.ecfg.decode_horizon
-        args, active = self._decode_args()
-        t0 = time.time()
-        t0_us = self.tracer.now_us()
-        toks, emits, acts, *kept, counts = self._run_offloaded(
-            self._decode, args
-        )
-        toks = np.asarray(toks)          # [H, B] (-1 where not emitted)
-        emits = np.asarray(emits)        # [H, B] bool
-        acts = np.asarray(acts)          # [H]
-        logits = np.asarray(kept[0]) if kept else None  # [H, B, V]
-        dt = time.time() - t0
-        stats = self._last_run_stats
-        # logical steps that emitted ≥ 1 token; trailing all-stopped scan
-        # steps computed garbage and recorded nothing
-        emitting = np.flatnonzero(emits.any(axis=1))
-        steps_run = len(emitting)
-        self.metrics.record_megastep(
-            steps_run, stats["compute_s"], stats["offload_s"],
-            stats["runs"], stats["runs"],
-        )
-        # the megastep span (engine track) plus one decode span per
-        # active slot, all sharing the megastep's extent — the per-slot
-        # view shows who actually emitted inside the fused program
-        self.tracer.complete(
-            "megastep", track="engine", cat="decode", start_us=t0_us,
-            args={"megastep": self._megastep_idx, "horizon": h,
-                  "active": int(active.sum()), "steps": steps_run,
-                  "runs": int(stats["runs"])},
-        )
-        for slot, req in self.scheduler.active.items():
-            self.tracer.complete(
-                "decode", track=f"slot{slot}", cat="decode", start_us=t0_us,
-                args={"rid": req.rid, "tokens": int(emits[:, slot].sum())},
+        with self.tracer.span("megastep", track="engine", cat="decode",
+                              megastep=self._megastep_idx,
+                              horizon=h) as mega:
+            with self.tracer.span("inputs", track="engine",
+                                  cat="decode") as inputs:
+                args, active = self._decode_args()
+            mega.args["active"] = int(active.sum())
+            toks, emits, acts, *kept, counts = self._run_offloaded(
+                self._decode, args
             )
-        self.tracer.counter(
-            "pool", track="engine",
-            page_util=self.cache.utilization,
-            queue_depth=self.scheduler.queue_depth,
-            active=int(active.sum()),
-        )
-        per_step_s = dt / max(steps_run, 1)
-        for s in emitting:
-            # queue depth / page utilization are genuinely constant
-            # within a megastep (all scheduling happens at the boundary)
-            self.metrics.record_decode_step(
-                per_step_s, int(emits[s].sum()), float(acts[s]),
-                self.scheduler.queue_depth,
-                page_utilization=self.cache.utilization,
-            )
-            self._record_capacity_util(counts[s], b)
-        if self.offload is not None:
-            self.metrics.record_expert_residency(self.offload.resident_bytes)
-        for slot, req in list(self.scheduler.active.items()):
-            last_s = 0
-            emitted = 0
-            for s in range(h):
-                if emits[s, slot]:
-                    req.out.append(int(toks[s, slot]))
-                    req.pos += 1
-                    if logits is not None:
-                        self.token_logits[req.rid].append(logits[s, slot])
-                    last_s = s
-                    emitted += 1
-            # fairness accounting: debit the tenant's WDRR grant and
-            # record the per-tenant token counters (policy witnesses)
-            self.scheduler.note_tokens(req.tenant, emitted)
-            self.metrics.record_tenant_tokens(req.tenant, emitted)
-            if req.done:
-                self.scheduler.finish(slot)
-                track = f"slot{slot}"
-                self.tracer.lifecycle(
-                    "release", track=track, rid=req.rid, slot=slot,
-                    step=self._step_idx + last_s,
+            with self.tracer.span("fetch", track="engine",
+                                  cat="decode") as fetch:
+                toks = np.asarray(toks)      # [H, B] (-1 where not emitted)
+                emits = np.asarray(emits)    # [H, B] bool
+                acts = np.asarray(acts)      # [H]
+                logits = np.asarray(kept[0]) if kept else None  # [H, B, V]
+            # the run and the fetch, the interval the step metrics spread
+            dt = (fetch.end_ns - inputs.end_ns) * 1e-9
+            with self.tracer.span("apply", track="engine", cat="decode"):
+                stats = self._last_run_stats
+                # logical steps that emitted ≥ 1 token; trailing
+                # all-stopped scan steps computed garbage and recorded
+                # nothing
+                emitting = np.flatnonzero(emits.any(axis=1))
+                steps_run = len(emitting)
+                self.metrics.record_megastep(
+                    steps_run, stats["compute_s"], stats["offload_s"],
+                    stats["runs"], stats["runs"],
                 )
-                self.tracer.flow("f", req.rid, track=track)
-        self._step_idx += steps_run
+                mega.args["steps"] = steps_run
+                mega.args["runs"] = int(stats["runs"])
+                # one decode span per active slot, from the megastep's
+                # start — the per-slot view shows who actually emitted
+                # inside the fused program
+                start_us = self.tracer.us(mega.start_ns)
+                for slot, req in self.scheduler.active.items():
+                    self.tracer.complete(
+                        "decode", track=f"slot{slot}", cat="decode",
+                        start_us=start_us,
+                        args={"rid": req.rid,
+                              "tokens": int(emits[:, slot].sum())},
+                    )
+                self.tracer.counter(
+                    "pool", track="engine",
+                    page_util=self.cache.utilization,
+                    queue_depth=self.scheduler.queue_depth,
+                    active=int(active.sum()),
+                )
+                per_step_s = dt / max(steps_run, 1)
+                for s in emitting:
+                    # queue depth / page utilization are genuinely
+                    # constant within a megastep (all scheduling happens
+                    # at the boundary)
+                    self.metrics.record_decode_step(
+                        per_step_s, int(emits[s].sum()), float(acts[s]),
+                        self.scheduler.queue_depth,
+                        page_utilization=self.cache.utilization,
+                    )
+                    self._record_capacity_util(counts[s], b)
+                if self.offload is not None:
+                    self.metrics.record_expert_residency(
+                        self.offload.resident_bytes
+                    )
+                for slot, req in list(self.scheduler.active.items()):
+                    last_s = 0
+                    emitted = 0
+                    for s in range(h):
+                        if emits[s, slot]:
+                            req.out.append(int(toks[s, slot]))
+                            req.pos += 1
+                            if logits is not None:
+                                self.token_logits[req.rid].append(
+                                    logits[s, slot]
+                                )
+                            last_s = s
+                            emitted += 1
+                    # fairness accounting: debit the tenant's WDRR grant
+                    # and record the per-tenant token counters (policy
+                    # witnesses)
+                    self.scheduler.note_tokens(req.tenant, emitted)
+                    self.metrics.record_tenant_tokens(req.tenant, emitted)
+                    if req.done:
+                        self.scheduler.finish(slot)
+                        track = f"slot{slot}"
+                        self.tracer.lifecycle(
+                            "release", track=track, rid=req.rid, slot=slot,
+                            step=self._step_idx + last_s,
+                        )
+                        self.tracer.flow("f", req.rid, track=track)
+                self._step_idx += steps_run
         self._megastep_idx += 1

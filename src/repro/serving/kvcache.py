@@ -552,21 +552,19 @@ class PagedKVCache:
         """Copy-on-write page duplication (device-side): K/V rows and,
         on quantized pools, their scale/zero rows move together so the
         copy dequantizes bit-identically to the original."""
-        t0 = self.tracer.now_us()
-        self.k = self.k.at[:, dst].set(self.k[:, src])
-        self.v = self.v.at[:, dst].set(self.v[:, src])
-        if self.quant is not None:
-            self.quant = {
-                name: a.at[:, dst].set(a[:, src])
-                for name, a in self.quant.items()
-            }
-        self.tracer.lifecycle(
-            "cow_copy", track="pool", rid=rid, src_page=src, dst_page=dst,
-        )
-        self.tracer.complete(
-            "cow_copy_span", track="pool", cat="kv", start_us=t0,
-            args={"src": src, "dst": dst},
-        )
+        with self.tracer.span("cow_copy_span", track="pool", cat="kv",
+                              src=src, dst=dst):
+            self.k = self.k.at[:, dst].set(self.k[:, src])
+            self.v = self.v.at[:, dst].set(self.v[:, src])
+            if self.quant is not None:
+                self.quant = {
+                    name: a.at[:, dst].set(a[:, src])
+                    for name, a in self.quant.items()
+                }
+            self.tracer.lifecycle(
+                "cow_copy", track="pool", rid=rid, src_page=src,
+                dst_page=dst,
+            )
 
     def acquire_slot(self, total_tokens: int,
                      prefix_entry: Optional[PrefixEntry] = None,
@@ -697,27 +695,24 @@ class PagedKVCache:
                 )
         blocks = self.slot_blocks[slot]
         idx = np.asarray(blocks, np.int32)
-        t0 = self.tracer.now_us()
-        swapped = SwappedKV(
-            k=np.array(self.k[:, idx]),
-            v=np.asarray(self.v[:, idx]),
-            n_tokens=n_tokens,
-            quant=(
-                {n: np.asarray(a[:, idx]) for n, a in self.quant.items()}
-                if self.quant is not None else None
-            ),
-        )
-        swapped.checksum = swapped.payload_checksum()
-        if spec is not None and spec.mode == "corrupt":
-            # in-transit damage: the checksum above describes the
-            # pristine payload, so swap-in's verification must trip
-            swapped.k.view(np.uint8).reshape(-1)[0] ^= 0xFF
-        self.release_slot(slot)
-        self.tracer.complete(
-            "kv_swap_out", track="pool", cat="kv", start_us=t0,
-            args={"slot": slot, "pages": swapped.n_pages,
-                  "bytes": swapped.nbytes},
-        )
+        with self.tracer.span("kv_swap_out", track="pool", cat="kv",
+                              slot=slot) as span:
+            swapped = SwappedKV(
+                k=np.array(self.k[:, idx]),
+                v=np.asarray(self.v[:, idx]),
+                n_tokens=n_tokens,
+                quant=(
+                    {n: np.asarray(a[:, idx]) for n, a in self.quant.items()}
+                    if self.quant is not None else None
+                ),
+            )
+            swapped.checksum = swapped.payload_checksum()
+            if spec is not None and spec.mode == "corrupt":
+                # in-transit damage: the checksum above describes the
+                # pristine payload, so swap-in's verification must trip
+                swapped.k.view(np.uint8).reshape(-1)[0] ^= 0xFF
+            self.release_slot(slot)
+            span.args.update(pages=swapped.n_pages, bytes=swapped.nbytes)
         return swapped
 
     def swap_in(self, slot: int, swapped: SwappedKV, rid: int = -1) -> int:
@@ -760,19 +755,18 @@ class PagedKVCache:
                 rid=(int(rid) if rid >= 0 else None),
             )
         idx = jnp.asarray(np.asarray(blocks, np.int32))
-        t0 = self.tracer.now_us()
-        self.k = self.k.at[:, idx].set(jnp.asarray(swapped.k, self.k.dtype))
-        self.v = self.v.at[:, idx].set(jnp.asarray(swapped.v, self.v.dtype))
-        if self.quant is not None:
-            self.quant = {
-                n: a.at[:, idx].set(jnp.asarray(swapped.quant[n]))
-                for n, a in self.quant.items()
-            }
-        self.tracer.complete(
-            "kv_swap_in", track="pool", cat="kv", start_us=t0,
-            args={"slot": slot, "pages": swapped.n_pages,
-                  "bytes": swapped.nbytes},
-        )
+        with self.tracer.span("kv_swap_in", track="pool", cat="kv",
+                              slot=slot, pages=swapped.n_pages,
+                              bytes=swapped.nbytes):
+            self.k = self.k.at[:, idx].set(
+                jnp.asarray(swapped.k, self.k.dtype))
+            self.v = self.v.at[:, idx].set(
+                jnp.asarray(swapped.v, self.v.dtype))
+            if self.quant is not None:
+                self.quant = {
+                    n: a.at[:, idx].set(jnp.asarray(swapped.quant[n]))
+                    for n, a in self.quant.items()
+                }
         return swapped.nbytes
 
     def release_slot(self, slot: int) -> None:

@@ -635,7 +635,14 @@ class ExpertOffloadManager:
         layer_of = np.arange(rows.shape[0]) % self.num_layers
         if not np.any((rows > 0) & ~resident[layer_of]):
             return 0, 0
-        t0 = self.tracer.now_us()
+        with self.tracer.span("expert_upload", track="experts",
+                              cat="offload", kind="miss") as span:
+            ups, nbytes = self._upload_missing(rows, layer_of)
+            span.args.update(uploads=ups, bytes=nbytes)
+            span.record = ups > 0  # kept only once rows were uploaded
+        return ups, nbytes
+
+    def _upload_missing(self, rows, layer_of) -> Tuple[int, int]:
         ups = 0
         nbytes = 0
         pending = {bk: [] for bk in self._bkeys}
@@ -677,11 +684,6 @@ class ExpertOffloadManager:
                     bk, pending[bk], pend_rows[bk] or None
                 )
                 self._refresh_map(bk)
-        if ups:
-            self.tracer.complete(
-                "expert_upload", track="experts", cat="offload", start_us=t0,
-                args={"kind": "miss", "uploads": ups, "bytes": nbytes},
-            )
         return ups, nbytes
 
     def update_stats(self, counts: np.ndarray) -> None:
@@ -733,7 +735,14 @@ class ExpertOffloadManager:
         """
         if not targets:
             return 0, 0
-        t0 = self.tracer.now_us()
+        with self.tracer.span("expert_upload", track="experts",
+                              cat="offload", kind="prefetch") as span:
+            ups, nbytes = self._upload_targets(targets)
+            span.args.update(uploads=ups, bytes=nbytes)
+            span.record = ups > 0  # kept only once rows were uploaded
+        return ups, nbytes
+
+    def _upload_targets(self, targets) -> Tuple[int, int]:
         ups = 0
         nbytes = 0
         pending = {bk: [] for bk in self._bkeys}
@@ -765,11 +774,6 @@ class ExpertOffloadManager:
                     bk, pending[bk], pend_rows[bk] or None
                 )
                 self._refresh_map(bk)
-        if ups:
-            self.tracer.complete(
-                "expert_upload", track="experts", cat="offload", start_us=t0,
-                args={"kind": "prefetch", "uploads": ups, "bytes": nbytes},
-            )
         return ups, nbytes
 
     def prefetch(self) -> Tuple[int, int]:

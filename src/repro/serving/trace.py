@@ -29,6 +29,17 @@ lifecycle spans/instants/flows, ``full`` additionally records per-step
 counter events (pool/queue gauges, routing drift/Gini) and feeds the
 expert-routing telemetry.
 
+**On the profiler's clock.** Every :meth:`SpanTracer.span` is also a
+``jax.profiler.TraceAnnotation`` of the same name, entered at every
+level: when ``jax.profiler`` is tracing, the engine's spans land in its
+host plane beside the device's programs and ops (when it is not, an
+annotation costs about a microsecond). Timestamps are read from the
+clock the profiler stamps host events with (``CLOCK_REALTIME``,
+``time.time_ns``): an event's ``ts_us`` is microseconds since
+:attr:`SpanTracer.origin_ns`, and a profiler event at ``start_ns`` of a
+trace whose ``Task Environment`` plane gives ``profile_start_time`` P
+sits at ``(P + start_ns - origin_ns) / 1e3`` on the same axis.
+
 **Metrics as a consumer.** Lifecycle facts the metrics used to
 book-keep in parallel (admission, release, preemption, swap-in) now
 flow through :meth:`SpanTracer.lifecycle`: consumers (the
@@ -60,15 +71,16 @@ serving smoke with tracing and validates every artifact::
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "TRACE_LEVELS",
+    "Span",
     "SpanTracer",
     "NULL_TRACER",
     "MetricsConsumer",
@@ -87,6 +99,50 @@ _LEVEL = {name: i for i, name in enumerate(TRACE_LEVELS)}
 _WALL_KEYS = ("ts_us", "dur_us")
 _PHASES = frozenset({"X", "i", "C", "s", "t", "f"})
 _ARG_TYPES = (str, int, float, bool, type(None))
+
+
+class Span:
+    """One timed stretch of host work (:meth:`SpanTracer.span`).
+
+    A profiler annotation of the same name brackets the body at every
+    trace level; the extent (``start_ns``/``end_ns``, on the tracer's
+    clock) is taken at every level too, so callers time work from the
+    span itself. At exit it is recorded through
+    :meth:`SpanTracer.complete` unless the body raised. The body may add
+    to :attr:`args` (values known only at exit, such as a run count) and
+    clear :attr:`record` to leave this one out of the in-memory record
+    (the profiler event stays).
+    """
+
+    __slots__ = ("tracer", "name", "track", "cat", "args", "record",
+                 "start_ns", "end_ns", "_ann")
+
+    def __init__(self, tracer: "SpanTracer", name: str, track: str,
+                 cat: str, args: Dict):
+        self.tracer, self.name, self.track, self.cat = tracer, name, track, cat
+        self.args = args
+        self.record = True
+        self.start_ns = self.end_ns = 0
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = time.time_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end_ns = time.time_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.record and exc_type is None:
+            self.tracer.complete(
+                self.name, track=self.track, cat=self.cat,
+                start_us=self.tracer.us(self.start_ns),
+                end_us=self.tracer.us(self.end_ns), args=self.args,
+            )
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
 
 
 class SpanTracer:
@@ -108,7 +164,7 @@ class SpanTracer:
         self.level = _LEVEL[level]
         self.consumers = list(consumers)
         self.events: List[Dict] = []
-        self._t0 = time.perf_counter()
+        self.origin_ns = time.time_ns()
 
     # ------------------------------------------------------------- state
     @property
@@ -125,11 +181,16 @@ class SpanTracer:
         """Drop recorded events and re-anchor the clock (e.g. after a
         warmup pass). Consumers and level are kept."""
         self.events.clear()
-        self._t0 = time.perf_counter()
+        self.origin_ns = time.time_ns()
+
+    def us(self, t_ns: int) -> float:
+        """A ``time.time_ns`` reading as microseconds since the origin."""
+        return (t_ns - self.origin_ns) * 1e-3
 
     def now_us(self) -> float:
-        """Wall-clock microseconds since tracer creation/reset."""
-        return (time.perf_counter() - self._t0) * 1e6
+        """Microseconds since tracer creation/reset, on the profiler's
+        host clock."""
+        return self.us(time.time_ns())
 
     def _record(self, ev: Dict) -> None:
         ev["seq"] = len(self.events)
@@ -152,18 +213,12 @@ class SpanTracer:
             "dur_us": round(max(end - start_us, 0.0), 3),
         })
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, track: str, cat: str, **args):
-        """Context-managed span; recorded as one "X" event at exit."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, track=track, cat=cat, start_us=t0,
-                          args=args)
+    def span(self, name: str, *, track: str, cat: str, **args) -> Span:
+        """``with tracer.span(...) as sp:`` brackets the body with a
+        profiler annotation ``name`` (no annotation args: the args stay
+        in the in-memory record) and times it; recorded as one "X" event
+        at exit when spans are enabled."""
+        return Span(self, name, track, cat, args)
 
     def instant(self, name: str, *, track: str, cat: str, **args) -> None:
         if not self.enabled:

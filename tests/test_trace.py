@@ -42,7 +42,7 @@ from repro.serving import (
     validate_chrome_trace,
     validate_events,
 )
-from repro.serving.trace import NULL_TRACER, gini
+from repro.serving.trace import NULL_TRACER, TRACE_LEVELS, gini
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +80,55 @@ def test_level_gating():
     with pytest.raises(ValueError, match="flow phase"):
         full.flow("x", 1, track="queue")
     assert NULL_TRACER.events == []  # the shared default stays inert
+
+
+def test_span_times_its_body_at_every_level_and_takes_args_at_exit():
+    """A span is timed whatever the level (callers read their intervals
+    from it); its args may be completed in the body, and it is recorded,
+    with those args, only at ``spans`` and above."""
+    for level in TRACE_LEVELS:
+        t = SpanTracer(level)
+        with t.span("compute", track="engine", cat="decode", run=1) as sp:
+            sp.args["runs"] = 2
+        assert sp.end_ns >= sp.start_ns > 0 and sp.seconds >= 0.0
+        if t.enabled:
+            (ev,) = t.events
+            assert ev["args"] == {"run": 1, "runs": 2}
+            assert ev["ts_us"] == pytest.approx(t.us(sp.start_ns), abs=1e-3)
+            assert ev["dur_us"] == pytest.approx(sp.seconds * 1e6, abs=1e-3)
+        else:
+            assert t.events == []
+
+
+def test_span_left_out_when_its_body_raises_or_it_opts_out():
+    t = SpanTracer("spans")
+    with pytest.raises(RuntimeError):
+        with t.span("sync", track="engine", cat="decode"):
+            raise RuntimeError("device lost")
+    with t.span("expert_upload", track="experts", cat="offload") as sp:
+        sp.record = False
+    with t.span("fetch", track="engine", cat="decode"):
+        pass
+    assert [e["name"] for e in t.events] == ["fetch"]
+
+
+def test_span_is_a_profiler_annotation_at_level_off(tmp_path):
+    """With tracing off, the profiler still sees every span by name."""
+    from jax.profiler import ProfileData
+
+    t = SpanTracer("off")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("megastep", track="engine", cat="decode"):
+            with t.span("fetch", track="engine", cat="decode"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert {"megastep", "fetch"} <= names
+    assert t.events == []
 
 
 def test_deterministic_projection_strips_wall_clock_only():
@@ -296,6 +345,8 @@ def test_trace_covers_full_lifecycle_with_preemption(dense_model):
         "enqueue", "admit", "prefill_chunk", "first_token", "compute",
         "megastep", "decode", "page_grow", "preempt", "kv_swap_out",
         "swap_in", "kv_swap_in", "release", "request", "pool",
+        "boundary", "plan", "prefill", "inputs", "dispatch", "sync",
+        "account", "sample", "fetch", "apply",
     } <= names
     assert engine.metrics.counters()["preemptions"], "trace must preempt"
     # the preempted request was re-admitted as resumed
