@@ -5,20 +5,24 @@ experts are **permuted so equal-width experts are contiguous** and stacked
 into ≤3 *buckets* (one per bit-width). Each bucket is padded to a multiple
 of the expert-parallel shard count (DESIGN.md §5.4).
 
-**Compute path (default: ``grouped``).** The capacity-dispatch layout is
-already expert-major — slot ``s`` owns rows ``[s·cap, (s+1)·cap)`` — so
-each bucket's slice is a token-sorted ragged batch in disguise: the
-occupied rows of every slot are a *prefix* (capacity dispatch assigns
-rank-within-expert destinations). :func:`compressed_expert_ffn` compacts
-those prefixes into back-to-back ``bm``-aligned groups, issues the
-bucket's whole SwiGLU as grouped GEMMs via :func:`repro.kernels.ops`
-(one fused gate/up call with the SwiGLU epilogue + one down call) with a
-scalar-prefetched ``block_expert`` table, and scatters the results back
-to the capacity layout. Row-blocks past the routed-token frontier are
-skipped inside the kernel (``num_active``), so the dead compute on
-unrouted capacity padding — which the old per-expert ``lax.scan`` paid
-in full, dequantizing every expert against every padded row — is gone.
-On TPU ``ops.moe_gmm`` lowers to the Pallas kernel in
+**Compute path (default: ``grouped``).** Each bucket's SwiGLU runs as
+grouped GEMMs via :func:`repro.kernels.ops` (one fused gate/up call with
+the SwiGLU epilogue + one down call) over a *compacted* row layout: one
+row per routed (token, choice) pair, pairs sorted by permuted slot, each
+slot's group padded to ``bm`` rows and the groups packed back to back,
+with a scalar-prefetched ``block_expert`` table naming each row block's
+expert. The buffer, and so the kernels' grid, is sized by a static bound
+on the routed pairs (:func:`grouped_extent`: about ``count + T·k/bm``
+blocks), not by the bucket's ``count·cap`` capacity rows, which under
+drop-free serving (``cf = num_experts``) are ``num_experts`` times the
+pairs. Blocks past the routed frontier (``num_active``) are skipped
+inside the kernel. On a single device :func:`ragged_expert_ffn` builds
+the layout straight from the pairs; the expert-parallel paths keep the
+capacity layout (slot ``s`` owns rows ``[s·cap, (s+1)·cap)``, occupied
+rows a prefix) and :func:`grouped_bucket_ffn` compacts it into the same
+rows. Either way each output row depends only on its own input row and
+its expert's weights, so both give the same bits. On TPU
+``ops.moe_gmm`` lowers to the Pallas kernel in
 :mod:`repro.kernels.moe_gmm`; on CPU it runs the jnp oracle
 (``moe_gmm_ref``), and tests opt into ``interpret``.
 
@@ -33,7 +37,7 @@ process-wide — it is read at trace time, so a jitted serving engine
 keeps whichever backend it was traced with.
 
 The router remap (original expert id → permuted slot) rides the routing
-top-k output, so the rest of the MoE layer (capacity dispatch, OTP
+top-k output, so the rest of the MoE layer (capacity semantics, OTP
 masking, combine) is unchanged.
 
 **Host-offloaded residency** (serving): a bucket may be split into a
@@ -85,6 +89,8 @@ __all__ = [
     "default_ffn_backend",
     "gmm_block_rows",
     "grouped_bucket_ffn",
+    "grouped_extent",
+    "ragged_expert_ffn",
 ]
 
 FFN_BACKENDS = ("grouped", "scan", "ref", "interpret")
@@ -319,18 +325,66 @@ def _gmm_parts(w: Dict, bits: int):
 def gmm_block_rows(cap: int) -> int:
     """Row-block size ``bm`` for the grouped path at capacity ``cap``.
 
-    ``bm`` must divide ``cap`` (so slot boundaries are block-aligned) and
-    trades MXU tile height against ragged-skip granularity: each
-    nonempty expert wastes < ``bm`` rows of compute, so smaller blocks
-    skip more dead padding while larger blocks feed the 128-row MXU
-    better. Default target 16 — drop-free serving capacities
-    (cf = num_experts) run single-digit-percent utilization, where skip
-    granularity dominates; override with ``REPRO_GMM_BM`` (e.g. 128 for
-    long-prefill TPU runs). Always a multiple of 8 because ``cap`` is.
+    ``bm`` must divide ``cap`` (so a slot's group never outgrows its
+    capacity in whole blocks) and trades MXU tile height against padding:
+    each nonempty expert pads its group to a multiple of ``bm``, so with
+    ``P`` routed pairs over ``c`` touched experts the grouped GEMMs walk
+    about ``c + P/bm`` row blocks (:func:`grouped_extent`). Few rows per
+    expert — decode, or short prefill chunks spread over many experts —
+    favour small blocks; default target 16, override with
+    ``REPRO_GMM_BM`` (e.g. 128 for long-prefill TPU runs). Always a
+    multiple of 8 because ``cap`` is.
     """
     target = int(os.environ.get("REPRO_GMM_BM", "0") or 0) or 16
     target = max(8, ((target + 7) // 8) * 8)  # sublane-align the target
     return math.gcd(cap, target)
+
+
+def grouped_extent(count: int, cap: int, pairs: int, bm: int) -> int:
+    """Rows of one bucket's compacted grouped-GEMM buffer (static).
+
+    The bucket receives at most ``p = min(pairs, count·cap)`` routed rows.
+    Its groups take ``Σ_s bm·ceil(fill_s/bm)`` rows; over ``n`` nonempty
+    slots that is at most ``bm·(n + floor((p − n)/bm))``, which grows with
+    ``n``, so ``n = min(count, p)`` bounds every routing. Where capacity
+    is tight the bound reaches ``count·cap``, today's full layout.
+    """
+    p = min(pairs, count * cap)
+    c = min(count, p)
+    return bm * min(count * cap // bm, c + (p - c) // bm)
+
+
+def _block_table(fill, count: int, rows: int, bm: int, rmap=None):
+    """``fill [count]`` → each slot's first compacted row ``[count]``, the
+    expert of every row block ``[rows/bm]`` and the live block count
+    ``num_active [1]``: slot groups packed back to back at ``bm``
+    boundaries, in slot order."""
+    padded = ((fill + bm - 1) // bm) * bm
+    nblk = padded // bm
+    block_expert = jnp.repeat(
+        jnp.arange(count, dtype=jnp.int32), nblk,
+        total_repeat_length=rows // bm,
+    )  # trailing pad entries repeat a valid id; num_active masks them
+    if rmap is not None:
+        block_expert = rmap[block_expert].astype(jnp.int32)
+    num_active = jnp.sum(nblk).astype(jnp.int32).reshape(1)
+    return jnp.cumsum(padded) - padded, block_expert, num_active
+
+
+def _bucket_gemms(xg, wdict, block_expert, num_active, *, bits, group, bm,
+                  kernel_backend):
+    """One bucket's SwiGLU as two grouped GEMMs over the compacted rows."""
+    gp, gs, gz = _gmm_parts(wdict["w_gate"], bits)
+    up, us, uz = _gmm_parts(wdict["w_up"], bits)
+    dp, ds, dz = _gmm_parts(wdict["w_down"], bits)
+    h = ops.moe_gmm_swiglu(
+        xg, gp, up, gs, gz, us, uz, block_expert, num_active,
+        bits=bits, group=group, backend=kernel_backend, bm=bm,
+    )
+    return ops.moe_gmm(
+        h, dp, ds, dz, block_expert, num_active,
+        bits=bits, group=group, backend=kernel_backend, bm=bm,
+    )
 
 
 def grouped_bucket_ffn(
@@ -344,6 +398,7 @@ def grouped_bucket_ffn(
     kernel_backend: Optional[str] = None,
     fill: Optional[jnp.ndarray] = None,
     rmap: Optional[jnp.ndarray] = None,
+    pairs: Optional[int] = None,
 ) -> jnp.ndarray:
     """One bucket's SwiGLU over its capacity slice as grouped GEMMs.
 
@@ -356,12 +411,14 @@ def grouped_bucket_ffn(
     occupancy is a *prefix* per slot (capacity dispatch ranks within the
     expert), so compaction is a pure index shuffle: slot ``s`` row ``j``
     (``j < fill[s]``) moves to ``offsets[s] + j`` where groups are packed
-    back-to-back at ``bm`` boundaries. The trailing ``num_active`` block
-    count lets the kernel skip every block past the routed-token
-    frontier; results are scattered back so unoccupied capacity rows are
-    exactly zero — identical to what the scan path computes for them.
-    Without ``fill`` every capacity row is treated as live (the layout
-    is already bm-aligned and expert-major, so no shuffle is needed).
+    back-to-back at ``bm`` boundaries. The compacted buffer, and with it
+    the kernels' grid, has :func:`grouped_extent` rows for ``pairs`` (the
+    most routed pairs the caller can send, static; default ``count·cap``)
+    and ``num_active`` skips the blocks past the routed-row frontier.
+    Results are scattered back so unoccupied capacity rows are exactly
+    zero — identical to what the scan path computes for them. Without
+    ``fill`` every capacity row is treated as live (the layout is already
+    bm-aligned and expert-major, so no shuffle is needed).
 
     ``rmap [count]`` folds host-offload residency into the scalar
     ``block_expert`` table instead of gathering the packed bucket.
@@ -369,48 +426,102 @@ def grouped_bucket_ffn(
     m = count * cap
     d = xb.shape[-1]
     bm = gmm_block_rows(cap)
-    if fill is not None:
-        fill = jnp.minimum(fill.astype(jnp.int32), cap)
-        padded = ((fill + bm - 1) // bm) * bm  # [count], bm | cap ⇒ Σ ≤ m
-        nblk = padded // bm
-        offsets = jnp.cumsum(padded) - padded  # exclusive
-        s_of = jnp.arange(m, dtype=jnp.int32) // cap
-        j_of = jnp.arange(m, dtype=jnp.int32) % cap
-        # capacity row (s, j) → compacted row; dropped/empty rows → m
-        gdest = jnp.where(j_of < fill[s_of], offsets[s_of] + j_of, m)
-        inv = jnp.zeros((m + 1,), jnp.int32)
-        inv = inv.at[gdest].set(jnp.arange(m, dtype=jnp.int32) + 1)[:m]
-        src = jnp.where(inv > 0, inv - 1, m)  # m = appended zero row
-        x_pad = jnp.concatenate([xb, jnp.zeros((1, d), xb.dtype)], axis=0)
-        xg = x_pad[src]
-        block_expert = jnp.repeat(
-            jnp.arange(count, dtype=jnp.int32), nblk,
-            total_repeat_length=m // bm,
-        )  # trailing pad entries repeat a valid id; num_active masks them
-        num_active = jnp.sum(nblk).astype(jnp.int32).reshape(1)
-    else:
-        xg = xb
-        gdest = None
+    kw = dict(bits=bits, group=group, bm=bm, kernel_backend=kernel_backend)
+    if fill is None:
         block_expert = jnp.repeat(jnp.arange(count, dtype=jnp.int32), cap // bm)
-        num_active = None
-    if rmap is not None:
-        block_expert = rmap[block_expert].astype(jnp.int32)
-
-    gp, gs, gz = _gmm_parts(wdict["w_gate"], bits)
-    up, us, uz = _gmm_parts(wdict["w_up"], bits)
-    dp, ds, dz = _gmm_parts(wdict["w_down"], bits)
-    h = ops.moe_gmm_swiglu(
-        xg, gp, up, gs, gz, us, uz, block_expert, num_active,
-        bits=bits, group=group, backend=kernel_backend, bm=bm,
-    )
-    yg = ops.moe_gmm(
-        h, dp, ds, dz, block_expert, num_active,
-        bits=bits, group=group, backend=kernel_backend, bm=bm,
-    )
-    if gdest is None:
-        return yg
+        if rmap is not None:
+            block_expert = rmap[block_expert].astype(jnp.int32)
+        return _bucket_gemms(xb, wdict, block_expert, None, **kw)
+    rows = grouped_extent(count, cap, m if pairs is None else pairs, bm)
+    fill = jnp.minimum(fill.astype(jnp.int32), cap)
+    offsets, block_expert, num_active = _block_table(fill, count, rows, bm, rmap)
+    s_of = jnp.arange(m, dtype=jnp.int32) // cap
+    j_of = jnp.arange(m, dtype=jnp.int32) % cap
+    # capacity row (s, j) → compacted row; dropped/empty rows → rows
+    gdest = jnp.where(j_of < fill[s_of], offsets[s_of] + j_of, rows)
+    inv = jnp.zeros((rows + 1,), jnp.int32)
+    inv = inv.at[gdest].set(jnp.arange(m, dtype=jnp.int32) + 1)[:rows]
+    src = jnp.where(inv > 0, inv - 1, m)  # m = appended zero row
+    x_pad = jnp.concatenate([xb, jnp.zeros((1, d), xb.dtype)], axis=0)
+    yg = _bucket_gemms(x_pad[src], wdict, block_expert, num_active, **kw)
     y_pad = jnp.concatenate([yg, jnp.zeros((1, d), yg.dtype)], axis=0)
     return y_pad[gdest]
+
+
+def ragged_expert_ffn(
+    ce: CompressedExperts,
+    x2: jnp.ndarray,
+    eids: jnp.ndarray,
+    cap: int,
+    *,
+    kernel_backend: Optional[str] = None,
+):
+    """Grouped SwiGLU straight from the routed pairs (single device).
+
+    ``x2 [T, D]`` tokens; ``eids [T·k]`` the permuted slot of each
+    (token, choice) pair in (t, k) order, ``ce.num_slots`` for a pair
+    that OTP pruned. The pairs are stably sorted by slot; a pair whose
+    rank in its slot is ``≥ cap`` is dropped, as
+    :func:`repro.models.moe.capacity_dispatch` drops it. Each bucket's
+    kept pairs form ``bm``-aligned slot groups in a buffer of
+    :func:`grouped_extent` rows for ``T·k`` pairs, and the buffers of all
+    buckets stand back to back. Every row sits where
+    :func:`grouped_bucket_ffn` would compact it, so the outputs are the
+    capacity path's, bit for bit, without its ``[num_slots·cap, D]``
+    buffer.
+
+    Returns ``(y [R, D], row [T·k], valid [T·k], fill [num_slots])``:
+    the grouped outputs, each pair's row of ``y`` (``R`` when dropped),
+    whether it holds one, and each slot's kept-pair count (what
+    :func:`repro.models.moe.slot_fill_counts` gives for the capacity
+    layout). Feed ``y, row, valid`` to :func:`repro.models.moe.combine`.
+    """
+    t, d = x2.shape
+    n = eids.shape[0]
+    k = n // t
+    bm = gmm_block_rows(cap)
+    eids = eids.astype(jnp.int32)
+    counts = jnp.zeros((ce.num_slots + 1,), jnp.int32).at[eids].add(1)
+    first = jnp.cumsum(counts) - counts
+    order = jnp.argsort(eids, stable=True)
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32) - first[eids[order]]
+    )
+    fill = jnp.minimum(counts[:-1], cap)
+    valid = (rank < cap) & (eids < ce.num_slots)
+
+    rows = [grouped_extent(m.count, cap, n, bm) for m in ce.meta]
+    total = sum(rows)
+    starts, tables = [], []
+    base = 0
+    for i, (m, r) in enumerate(zip(ce.meta, rows)):
+        rmap = None if ce.resident_map is None else ce.resident_map[f"b{i}"]
+        offsets, block_expert, num_active = _block_table(
+            jax.lax.slice_in_dim(fill, m.start, m.start + m.count),
+            m.count, r, bm, rmap,
+        )
+        starts.append(base + offsets)
+        tables.append((block_expert, num_active))
+        base += r
+    slot_start = jnp.concatenate(starts + [jnp.full((1,), total, jnp.int32)])
+    row = jnp.where(valid, slot_start[eids] + rank, total)
+    inv = jnp.zeros((total + 1,), jnp.int32)
+    inv = inv.at[row].set(jnp.arange(n, dtype=jnp.int32) + 1)[:total]
+    src = jnp.where(inv > 0, (inv - 1) // k, t)  # t = appended zero row
+    xg = jnp.concatenate([x2, jnp.zeros((1, d), x2.dtype)], axis=0)[src]
+
+    ys = []
+    base = 0
+    for i, (m, r, (block_expert, num_active)) in enumerate(
+        zip(ce.meta, rows, tables)
+    ):
+        ys.append(_bucket_gemms(
+            jax.lax.slice_in_dim(xg, base, base + r), ce.arrays[f"b{i}"],
+            block_expert, num_active, bits=m.bits, group=ce.group, bm=bm,
+            kernel_backend=kernel_backend,
+        ))
+        base += r
+    return jnp.concatenate(ys, axis=0), row, valid, fill
 
 
 def compressed_expert_ffn(
@@ -418,6 +529,7 @@ def compressed_expert_ffn(
     *,
     backend: Optional[str] = None,
     slot_fill: Optional[jnp.ndarray] = None,
+    pairs: Optional[int] = None,
 ) -> jnp.ndarray:
     """SwiGLU over permuted capacity layout ``xp [num_slots*cap, D]``.
 
@@ -425,7 +537,8 @@ def compressed_expert_ffn(
     GEMM calls — fused gate/up with the SwiGLU epilogue, then down —
     through :func:`grouped_bucket_ffn` (see its docstring for the
     compacted ragged layout driven by ``slot_fill``, the per-permuted-
-    slot occupied-row counts from capacity dispatch). With a resident
+    slot occupied-row counts from capacity dispatch, and sized by
+    ``pairs``, the number of routed pairs dispatched). With a resident
     partition (``ce.resident_map``) the indirection is folded into the
     scalar ``block_expert`` table once per bucket, before the GEMM —
     never a per-step weight gather (non-resident slots read row 0, which
@@ -493,7 +606,7 @@ def compressed_expert_ffn(
         if ep == 1:
             y = grouped_bucket_ffn(
                 xb, b, bits=m.bits, group=ce.group, count=m.count, cap=cap,
-                kernel_backend=kb, fill=fill, rmap=rmap,
+                kernel_backend=kb, fill=fill, rmap=rmap, pairs=pairs,
             )
         else:
             if rmap is not None:
@@ -508,7 +621,7 @@ def compressed_expert_ffn(
             def gfn(xe, we, fe, bits=m.bits):
                 return grouped_bucket_ffn(
                     xe, we, bits=bits, group=ce.group, count=local, cap=cap,
-                    kernel_backend=kb, fill=fe,
+                    kernel_backend=kb, fill=fe, pairs=pairs,
                 )
 
             if fill is None:
@@ -550,7 +663,9 @@ def compressed_moe_layer(
     (zero all-to-all — see :mod:`repro.parallel.ep_shardmap`); a
     host-offloaded ``ce`` (``resident_map`` set) always takes the local
     path, which folds the resident-row indirection into the grouped
-    dispatch tables.
+    dispatch tables. On a single device the grouped path dispatches
+    straight from the routed pairs (:func:`ragged_expert_ffn`); the scan
+    path and an ``ep > 1`` model axis go through the capacity layout.
     """
     from ..models.moe import ep_shardmap_ok
     from ..parallel.sharding import current_mesh
@@ -593,27 +708,39 @@ def compressed_moe_layer(
     # per-slot dispatch counts (post-mask, padding-weighted): the serving
     # offload manager's router statistic. The drop bucket (row num_slots)
     # absorbs masked / padded picks and is discarded.
-    eff = slots.reshape(-1)
+    routed = slots.reshape(-1)
     if mask is not None:
-        eff = jnp.where(mask.reshape(-1) > 0, eff, ce.num_slots)
+        routed = jnp.where(mask.reshape(-1) > 0, routed, ce.num_slots)
+    counted = routed  # padded tokens still take rows, but are not counted
     if count_weight is not None:
         cw = jnp.repeat(count_weight.reshape(-1).astype(bool), k)
-        eff = jnp.where(cw, eff, ce.num_slots)
+        counted = jnp.where(cw, routed, ce.num_slots)
     slot_counts = (
-        jnp.zeros((ce.num_slots + 1,), jnp.int32).at[eff].add(1)[:-1]
+        jnp.zeros((ce.num_slots + 1,), jnp.int32).at[counted].add(1)[:-1]
     )
     cap = dispatch_capacity(cfg, t, capacity_factor)
-    xp, dest, valid, gflat = capacity_dispatch(
-        x2, slots, gates, ce.num_slots, cap, mask
-    )
-    # occupied-row counts after capacity clipping: occupancy is a prefix
-    # per slot, so these drive the grouped path's ragged compaction
-    slot_fill = slot_fill_counts(dest, valid, ce.num_slots, cap)
-    xp = shard(xp, "moe_ed")
-    yp = compressed_expert_ffn(
-        ce, xp, cap, backend=ffn_backend, slot_fill=slot_fill
-    )
-    y = combine(yp, dest, valid, gflat, t, k)
+    path, kb = _resolve_backend(ffn_backend)
+    if path == "grouped" and model_axis_size() == 1:
+        gflat = gates.reshape(-1)
+        if mask is not None:
+            gflat = gflat * mask.reshape(-1)
+        yg, row, valid, _ = ragged_expert_ffn(
+            ce, x2, routed, cap, kernel_backend=kb
+        )
+        y = combine(yg, row, valid, gflat, t, k)
+    else:
+        xp, dest, valid, gflat = capacity_dispatch(
+            x2, slots, gates, ce.num_slots, cap, mask
+        )
+        # occupied-row counts after capacity clipping: occupancy is a
+        # prefix per slot, so these drive the grouped path's compaction
+        slot_fill = slot_fill_counts(dest, valid, ce.num_slots, cap)
+        xp = shard(xp, "moe_ed")
+        yp = compressed_expert_ffn(
+            ce, xp, cap, backend=ffn_backend, slot_fill=slot_fill,
+            pairs=t * k,
+        )
+        y = combine(yp, dest, valid, gflat, t, k)
     if "shared" in p:
         y = y + mlp(p["shared"], x2)
     info = {

@@ -11,14 +11,16 @@ Because every PMQ bit-width rides the same (scale, zero) affine form
 (1-bit: scale=2α, zero=0.5 — see ``quantize_to_packed``), a *bit-bucketed*
 MoE layer issues one ``moe_gmm`` per bucket with experts of equal width.
 
-**Ragged-length handling**: the compacted token-sorted layout
-(:func:`repro.core.compressed_moe.compressed_expert_ffn`) packs each
-expert's *routed* rows into bm-aligned groups at the front of a
-static-shape buffer; ``num_active [1]`` (second scalar-prefetch operand)
-tells the kernel how many leading row-blocks actually carry tokens.
-Blocks past it skip the unpack/dequant/MXU work entirely and write
-zeros — the dead capacity padding costs (almost) nothing, while the
-grid, and therefore the jitted program, keeps its static shape.
+**Ragged-length handling**: the caller
+(:mod:`repro.core.compressed_moe`) gives the kernel one row per routed
+(token, choice) pair, sorted by expert, each expert's group padded to
+``bm`` rows, in a static-shape buffer whose extent is a bound on the
+routed pairs (``grouped_extent``), not the experts' capacity. The grid
+follows ``x_sorted.shape[0]``, so it walks about ``experts + pairs/bm``
+row blocks. ``num_active [1]`` (second scalar-prefetch operand) tells
+the kernel how many leading row-blocks carry tokens; blocks past it skip
+the unpack/dequant/MXU work and write zeros, so the jitted program keeps
+its static shape whatever the routing.
 
 **SwiGLU epilogue** (:func:`moe_gmm_swiglu_pallas`): the gate and up
 projections share their ``x`` tile and accumulate side by side in VMEM;
@@ -339,8 +341,9 @@ def pad_groups(
     The *compacted* variant of this layout — groups packed back-to-back at
     bm boundaries with a ``num_active`` block count instead of a fixed
     per-expert stride — is built by
-    :func:`repro.core.compressed_moe.compressed_expert_ffn` directly on
-    the capacity-dispatch layout.
+    :func:`repro.core.compressed_moe.ragged_expert_ffn` from the routed
+    pairs, and by :func:`repro.core.compressed_moe.grouped_bucket_ffn`
+    from the capacity-dispatch layout.
     """
     e = group_sizes.shape[0]
     assert capacity % bm == 0

@@ -124,8 +124,9 @@ def compressed_moe_region_sharded(
     each shard runs its local share of every bucket through the same
     grouped-GEMM primitive as the local path
     (:func:`repro.core.compressed_moe.grouped_bucket_ffn`): occupied rows
-    compact into bm-aligned ragged groups, one fused gate/up + one down
-    ``ops.moe_gmm`` call per bucket, dead capacity blocks skipped via
+    compact into bm-aligned ragged groups in a buffer sized by the
+    shard's routed pairs, one fused gate/up + one down ``ops.moe_gmm``
+    call per bucket, blocks past the routed frontier skipped via
     ``num_active``. ``ffn_backend="scan"`` keeps the legacy one-expert-
     at-a-time scan (dequant-matmul through ``ops.quant_matmul_parts``,
     so TPU shards still get the Pallas dequant-GEMM).
@@ -302,7 +303,7 @@ def compressed_moe_region_sharded(
             fill = jax.lax.slice_in_dim(local_fill, st_loc, st_loc + cnt_loc)
             y = cmoe.grouped_bucket_ffn(
                 xb, wdict, bits=m.bits, group=ce.group, count=cnt_loc,
-                cap=cap, kernel_backend=kb, fill=fill,
+                cap=cap, kernel_backend=kb, fill=fill, pairs=t * k,
             )
             ys.append(y)
         yp = jnp.concatenate(ys, axis=0)

@@ -4,11 +4,17 @@ Contract: the grouped path — ragged compaction + ``ops.moe_gmm`` /
 ``ops.moe_gmm_swiglu`` with ``num_active`` block skipping — computes the
 same thing as the legacy per-expert scan for every routing pattern:
 bit-bucket mixes, OTP masks, capacity clipping, empty experts, resident
-partitions, and expert-parallel reshapes. Plus: the Pallas kernels match
-their jnp oracles in interpret mode, and the serving engine's greedy
-outputs are unchanged under the default (grouped) backend.
+partitions, and expert-parallel reshapes. The single-device layer builds
+that layout straight from the routed pairs and gives the capacity
+layout's outputs bit for bit, in a buffer sized by the pairs
+(``grouped_extent``), which holds every routing. Plus: the Pallas
+kernels match their jnp oracles in interpret mode, and the serving
+engine's greedy outputs are unchanged under the default (grouped)
+backend.
 """
 import dataclasses
+import itertools
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +23,16 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import compressed_moe as cm
+from repro.core import otp as otp_mod
 from repro.core.quantizers import quantize_to_packed
 from repro.kernels import ops, ref
-from repro.models.moe import capacity_dispatch, slot_fill_counts
+from repro.models.moe import (
+    capacity_dispatch,
+    combine,
+    dispatch_capacity,
+    route_topk,
+    slot_fill_counts,
+)
 
 
 def _experts(e, d, f, seed=0):
@@ -69,11 +82,13 @@ def test_grouped_matches_scan_fuzzed(bits_seed, t, k, cap, mask_p):
     xp, fill, dest, valid = _routed(ce, t, k, cap, bits_seed, mask_p)
     y_scan = np.asarray(cm.compressed_expert_ffn(ce, xp, cap, backend="scan"))
     y_ref = np.asarray(
-        cm.compressed_expert_ffn(ce, xp, cap, backend="ref", slot_fill=fill)
+        cm.compressed_expert_ffn(
+            ce, xp, cap, backend="ref", slot_fill=fill, pairs=t * k
+        )
     )
     y_int = np.asarray(
         cm.compressed_expert_ffn(
-            ce, xp, cap, backend="interpret", slot_fill=fill
+            ce, xp, cap, backend="interpret", slot_fill=fill, pairs=t * k
         )
     )
     np.testing.assert_allclose(y_ref, y_scan, rtol=2e-4, atol=2e-4)
@@ -161,6 +176,182 @@ def test_grouped_ep_reshape_equivalent(monkeypatch):
         cm.compressed_expert_ffn(ce, xp, cap, backend="ref", slot_fill=fill)
     )
     np.testing.assert_allclose(y2, y1, rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------ ragged dispatch == capacity layout
+def _tiny_layer(e, d, k, seed):
+    """Router, OTP router and PMQ experts of a tiny compressed layer."""
+    ce = cm.build_compressed_experts(
+        _experts(e, d, 48, seed=seed), [1, 2, 2, 3, 3, 3, 2, 1][:e],
+        group=16, ep=1, refine=False,
+    )
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    p = {"router": {"w": jax.random.normal(keys[0], (d, e)) / d**0.5}}
+    otp = otp_mod.init_otp_router(keys[1], d, k)
+    return p, ce, otp
+
+
+def _with_resident_map(ce):
+    """Every bucket stored in reversed row order behind a resident map."""
+    arrays = {b: jax.tree.map(lambda a: a[::-1], w) for b, w in ce.arrays.items()}
+    rmap = {
+        f"b{i}": jnp.arange(m.count, dtype=jnp.int32)[::-1]
+        for i, m in enumerate(ce.meta)
+    }
+    return dataclasses.replace(
+        ce, arrays=arrays, resident_map=rmap,
+        resident_rows=tuple(m.count for m in ce.meta),
+    )
+
+
+def _capacity_layer(p, ce, x2, cfg, cf, otp, backend):
+    """The layer through the ``[num_slots·cap, D]`` capacity layout."""
+    t, k = x2.shape[0], cfg.top_k
+    _, idx, gates = route_topk(p["router"], x2, k)
+    mask = None if otp is None else otp_mod.otp_mask(otp, x2, idx, gates)
+    slots = ce.slot_of_expert[idx]
+    cap = dispatch_capacity(cfg, t, cf)
+    xp, dest, valid, gflat = capacity_dispatch(
+        x2, slots, gates, ce.num_slots, cap, mask
+    )
+    fill = slot_fill_counts(dest, valid, ce.num_slots, cap)
+    yp = cm.compressed_expert_ffn(ce, xp, cap, backend=backend, slot_fill=fill)
+    yp_ragged = cm.compressed_expert_ffn(
+        ce, xp, cap, backend=backend, slot_fill=fill, pairs=t * k
+    )
+    np.testing.assert_array_equal(np.asarray(yp_ragged), np.asarray(yp))
+    eff = slots.reshape(-1)
+    if mask is not None:
+        eff = jnp.where(mask.reshape(-1) > 0, eff, ce.num_slots)
+    y = combine(yp, dest, valid, gflat, t, k)
+    return y, fill, eff, cap, valid
+
+
+@pytest.mark.parametrize("resident", [False, True], ids=["all", "resident"])
+@pytest.mark.parametrize("use_otp", [False, True], ids=["dense", "otp"])
+@pytest.mark.parametrize("cf", [None, 1.0], ids=["drop_free", "tight"])
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_ragged_layer_bitwise_equals_capacity_path(backend, cf, use_otp,
+                                                   resident):
+    """The single-device layer (pairs → compacted rows) gives the capacity
+    path's outputs bit for bit, with the same per-slot fill and
+    ``slot_counts``, whether capacity drops pairs or not."""
+    e, d, k, t = 8, 32, 3, 24
+    p, ce, otp = _tiny_layer(e, d, k, seed=40)
+    if resident:
+        ce = _with_resident_map(ce)
+    cfg = SimpleNamespace(num_experts=e, top_k=k, moe_capacity_factor=float(e))
+    rng = np.random.default_rng(41)
+    # a shared offset skews the router, so tight capacity must drop pairs
+    x2 = jnp.asarray(rng.normal(size=(t, d)) + 2.0 * rng.normal(size=(1, d)),
+                     jnp.float32)
+    weight = jnp.asarray(np.arange(t) % 5 != 0)  # some padded tokens
+    y, info = cm.compressed_moe_layer(
+        p, ce, x2.reshape(2, t // 2, d), cfg, otp_params=otp if use_otp else None,
+        capacity_factor=cf, count_weight=weight, ffn_backend=backend,
+    )
+    y_cap, fill, eff, cap, valid = _capacity_layer(
+        p, ce, x2, cfg, cf, otp if use_otp else None, backend
+    )
+    np.testing.assert_array_equal(np.asarray(y).reshape(t, d), np.asarray(y_cap))
+    if cf is not None:
+        assert not np.all(np.asarray(valid)[np.asarray(eff) < ce.num_slots])
+    else:
+        assert np.all(np.asarray(valid)[np.asarray(eff) < ce.num_slots])
+    yg, row, valid_r, fill_r = cm.ragged_expert_ffn(
+        ce, x2, eff, cap, kernel_backend=backend
+    )
+    np.testing.assert_array_equal(np.asarray(fill_r), np.asarray(fill))
+    np.testing.assert_array_equal(np.asarray(valid_r), np.asarray(valid))
+    counted = np.where(np.repeat(np.asarray(weight), k), np.asarray(eff),
+                       ce.num_slots)
+    np.testing.assert_array_equal(
+        np.asarray(info["slot_counts"]),
+        np.bincount(counted, minlength=ce.num_slots + 1)[:-1],
+    )
+    bm = cm.gmm_block_rows(cap)
+    assert yg.shape[0] == sum(
+        cm.grouped_extent(m.count, cap, t * k, bm) for m in ce.meta
+    )
+
+
+def _worst_fill(count, pairs, bm):
+    """A routing that reaches the bound: one pair on every slot, then the
+    rest in whole blocks, slot after slot."""
+    fill = np.ones(count, np.int64) if pairs >= count else (
+        np.arange(count) < pairs).astype(np.int64)
+    rest = pairs - int(fill.sum())
+    for s in itertools.cycle(range(count)):
+        if rest < bm:
+            break
+        fill[s] += bm
+        rest -= bm
+    fill[0] += rest  # inside a block slot 0 has already opened
+    return fill
+
+
+@pytest.mark.parametrize("t", [16, 64])
+@pytest.mark.parametrize(
+    "routing", ["one_expert", "one_per_expert", "one_bucket", "worst_bucket"]
+)
+def test_grouped_extent_holds_every_routing(routing, t):
+    """At 64 experts, top-6, in three bit buckets and drop-free capacity,
+    each bucket's compacted rows fit :func:`grouped_extent` for
+    adversarial routings and never pass ``count·cap``; at the decode
+    shape (64 tokens) the extent is at most an eighth of ``count·cap``."""
+    k, e = 6, 64
+    bits = [1] * 16 + [2] * 29 + [3] * 19
+    ce = cm.build_compressed_experts(
+        _experts(e, 16, 16, seed=50), bits, group=16, ep=1, refine=False
+    )
+    cfg = SimpleNamespace(num_experts=e, top_k=k, moe_capacity_factor=float(e))
+    cap, n = dispatch_capacity(cfg, t), t * k
+    bm = cm.gmm_block_rows(cap)
+    big = max(ce.meta, key=lambda m: m.count)
+    if routing == "one_expert":
+        eids = np.zeros(n, np.int64)
+    elif routing == "one_per_expert":
+        eids = np.arange(n) % e
+    elif routing == "one_bucket":
+        eids = big.start + np.arange(n) % big.count
+    else:
+        fill = _worst_fill(big.count, n, bm)
+        eids = big.start + np.repeat(np.arange(big.count), fill)
+    x2 = jnp.asarray(np.random.default_rng(51).normal(size=(t, 16)), jnp.float32)
+    yg, row, valid, fill = cm.ragged_expert_ffn(ce, x2, jnp.asarray(eids), cap)
+    assert np.all(np.asarray(valid))  # drop-free: every pair keeps its row
+    rows = np.asarray(row)
+    assert len(set(rows.tolist())) == n and rows.max() < yg.shape[0]
+    fill = np.asarray(fill)
+    base = 0
+    for m in ce.meta:
+        extent = cm.grouped_extent(m.count, cap, n, bm)
+        f = fill[m.start:m.start + m.count]
+        used = int((-(-f // bm) * bm).sum())
+        assert used <= extent <= m.count * cap
+        mine = (rows >= base) & (rows < base + extent)
+        assert mine.sum() == f.sum()  # the bucket's rows stay in its buffer
+        if routing == "worst_bucket" and m is big:
+            assert used == extent  # the bound is reached
+        if t == 64:
+            assert 8 * extent <= m.count * cap
+        base += extent
+    assert yg.shape[0] == base
+
+
+@pytest.mark.parametrize("count,cap", [(1, 16), (2, 8), (2, 24), (3, 16)])
+def test_grouped_extent_is_the_worst_case(count, cap):
+    """For every pair count, :func:`grouped_extent` equals the most rows
+    any fill of ``count`` slots (each ≤ ``cap``) takes in bm-aligned
+    groups: it holds every routing, and no smaller bound does."""
+    bm = cm.gmm_block_rows(cap)
+    fills = np.asarray(list(itertools.product(range(cap + 1), repeat=count)))
+    used = (-(-fills // bm) * bm).sum(axis=1)
+    total = fills.sum(axis=1)
+    for pairs in range(1, count * cap + 1):
+        assert cm.grouped_extent(count, cap, pairs, bm) == used[
+            total <= pairs
+        ].max()
 
 
 def test_bad_backend_rejected():

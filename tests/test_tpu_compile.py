@@ -23,7 +23,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
-from repro.core.compressed_moe import BucketMeta, CompressedExperts
+from repro.core.compressed_moe import (
+    BucketMeta,
+    CompressedExperts,
+    gmm_block_rows,
+    grouped_extent,
+)
 from repro.core.packing import PackedTensor
 from repro.kernels import ops
 
@@ -102,8 +107,9 @@ def test_paged_attention_compiles(one_chip, hkv, g, quant):
 )
 @pytest.mark.parametrize("swiglu", [False, True], ids=["gmm", "swiglu"])
 def test_moe_gmm_compiles(one_chip, swiglu, k, n, bits):
-    m = EXPERT_ROWS * CAP
-    bm = 16
+    # the compacted rows of one bucket for a decode step's routed pairs
+    bm = gmm_block_rows(CAP)
+    m = grouped_extent(EXPERT_ROWS, CAP, CAP, bm)
     w = _packed(EXPERT_ROWS, k, n, bits)
     sz = ((EXPERT_ROWS, k // GROUP, n), jnp.float32)
     x = ((m, k), jnp.bfloat16)
@@ -231,7 +237,8 @@ def test_served_param_specs_match_compression():
 
 def test_decode_megastep_compiles(one_chip, monkeypatch):
     """The engine's horizon-8 decode program at moonshot widths (2 of 48
-    layers, 8 slots, drop-free capacity) calls the Pallas kernels."""
+    layers, 8 slots, drop-free capacity, grouped GEMMs over the routed
+    pairs' compacted rows) calls the Pallas kernels."""
     from repro.serving.engine import _jitted_steps
 
     # the code asks jax for the platform and sees the CPU; the described
