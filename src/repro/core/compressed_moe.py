@@ -186,22 +186,27 @@ jax.tree_util.register_pytree_node(
 )
 
 
-def _pack_stack(ws: List[np.ndarray], bits: int, group: int,
-                codes_list=None, scales=None, zeros=None,
-                refine: bool = True) -> Dict:
-    """Stack per-expert packed tensors of one bucket (shared bit-width)."""
+def _pack_stack(ws, ids: List[int], bits: int, group: int,
+                gptq=None, refine: bool = True) -> Dict:
+    """Stack the packed experts ``ws[i]`` for ``i`` in ``ids`` (one bucket,
+    one bit-width). Each expert is cast to float32 and packed on its
+    device, which is waited for before the next expert is dispatched, so
+    that only one expert's float32 copy and temporaries are ever live;
+    ``gptq[i]`` (optional) gives expert ``i``'s GPTQ codes/scales."""
     pts = []
-    for i, w in enumerate(ws):
+    for i in ids:
         kw = {}
-        if codes_list is not None:
+        if gptq is not None:
             kw = {
-                "codes": jnp.asarray(codes_list[i]),
-                "scale": jnp.asarray(scales[i]),
-                "zero": jnp.asarray(zeros[i]),
+                "codes": jnp.asarray(gptq[i].codes),
+                "scale": jnp.asarray(gptq[i].scale),
+                "zero": jnp.asarray(gptq[i].zero),
             }
-        pts.append(
-            quantize_to_packed(jnp.asarray(w), bits, group=group, refine=refine, **kw)
-        )
+        w = jnp.asarray(ws[i], jnp.float32)
+        pts.append(jax.block_until_ready(
+            quantize_to_packed(w, bits, group=group, refine=refine, **kw)
+        ))
+        del w
     out: Dict = {
         "scale": jnp.stack([p.scale for p in pts]),
         "zero": jnp.stack([p.zero for p in pts]),
@@ -225,7 +230,9 @@ def build_compressed_experts(
 ) -> CompressedExperts:
     """Quantize + bucket one layer's experts.
 
-    ``experts`` = {"w_gate": [E, D, F], "w_up": [E, D, F], "w_down": [E, F, D]}.
+    ``experts`` = {"w_gate": [E, D, F], "w_up": [E, D, F], "w_down": [E, F, D]}
+    (host or device arrays, or anything whose ``[i]`` is expert ``i`` and
+    whose ``shape`` is theirs); each expert is packed on the device.
     ``gptq_results[(expert, name)]`` optionally carries GPTQ codes/scales
     (:class:`repro.core.gptq.GPTQResult`) — otherwise RTN/HQQ packing.
     ``ep`` = expert-parallel shard count (buckets padded to multiples).
@@ -236,10 +243,7 @@ def build_compressed_experts(
     meta: List[BucketMeta] = []
     arrays: Dict = {}
     slot_of_expert = np.full(e, -1, np.int64)
-    wg = np.asarray(experts["w_gate"], np.float32)
-    wu = np.asarray(experts["w_up"], np.float32)
-    wd = np.asarray(experts["w_down"], np.float32)
-    d, f = wg.shape[1], wg.shape[2]
+    d, f = experts["w_gate"].shape[1:]
     slot = 0
     for bits in sorted(set(bits_arr.tolist())):
         ids = [int(i) for i in order if bits_arr[i] == bits]
@@ -250,19 +254,12 @@ def build_compressed_experts(
         padded = count + pad
         pick = ids + [ids[-1]] * pad  # dummy slots clone the last expert
         bdict = {}
-        for name, w in (("w_gate", wg), ("w_up", wu), ("w_down", wd)):
+        for name in ("w_gate", "w_up", "w_down"):
+            gptq = None
             if gptq_results is not None:
-                codes = [gptq_results[(i, name)].codes for i in pick]
-                scales = [gptq_results[(i, name)].scale for i in pick]
-                zeros = [gptq_results[(i, name)].zero for i in pick]
-                bdict[name] = _pack_stack(
-                    [w[i] for i in pick], bits, group, codes, scales, zeros,
-                    refine=refine,
-                )
-            else:
-                bdict[name] = _pack_stack(
-                    [w[i] for i in pick], bits, group, refine=refine
-                )
+                gptq = {i: gptq_results[(i, name)] for i in set(pick)}
+            bdict[name] = _pack_stack(experts[name], pick, bits, group,
+                                      gptq, refine=refine)
         arrays[f"b{len(meta)}"] = bdict
         meta.append(BucketMeta(bits=bits, start=slot, count=padded))
         slot += padded

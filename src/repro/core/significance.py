@@ -8,19 +8,17 @@ Three signals per expert, gathered on a calibration set:
   output with full-precision experts and with only expert *i* quantized to
   *j* bits.
 
-These are model-agnostic: routing statistics accumulate from ``(top-k
-indices, top-k gates)`` streams, and ``eps`` is computed through a
-caller-supplied layer-forward closure so any MoE variant plugs in.
+Routing statistics accumulate here from ``(top-k indices, top-k gates)``
+streams; ``eps`` is computed by :func:`repro.core.pipeline.compute_eps`,
+one expert of one layer at a time.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RouterStats", "expert_eps", "importance"]
+__all__ = ["RouterStats", "importance"]
 
 
 @dataclasses.dataclass
@@ -57,30 +55,6 @@ class RouterStats:
     def w(self) -> np.ndarray:
         """Mean routing weight ``Σ sigma / N``."""
         return self.weight_sums / max(self.tokens, 1)
-
-
-def expert_eps(
-    layer_forward: Callable[[Sequence], jnp.ndarray],
-    expert_weights: Sequence,
-    quantize_expert: Callable[[object, int], object],
-    bits_options: Sequence[int] = (1, 2, 3),
-) -> np.ndarray:
-    """Eq. 6: ``eps[i, j] = ||F(theta) - F(theta[e_i -> Q(e_i, j)])||_F``.
-
-    ``layer_forward(experts) -> output`` runs the MoE layer on (captured)
-    calibration activations; ``quantize_expert(e, bits)`` returns the
-    fake-quantized (quantize→dequantize) weights of one expert.
-    """
-    base = layer_forward(list(expert_weights))
-    n = len(expert_weights)
-    eps = np.zeros((n, len(bits_options)), np.float64)
-    for i in range(n):
-        for j, bits in enumerate(bits_options):
-            perturbed = list(expert_weights)
-            perturbed[i] = quantize_expert(expert_weights[i], bits)
-            out = layer_forward(perturbed)
-            eps[i, j] = float(jnp.linalg.norm((out - base).astype(jnp.float32)))
-    return eps
 
 
 def importance(

@@ -793,4 +793,17 @@ def unstack_blocks(params, cfg):
 
 
 def restack_blocks(block_list):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *block_list)
+    """Stack per-layer block pytrees (of one structure) into one tree.
+    Empties ``block_list`` and drops each layer's piece of a leaf once
+    that leaf is stacked, so the layers are never held twice."""
+    flat = [jax.tree_util.tree_flatten(b) for b in block_list]
+    block_list.clear()
+    treedef = flat[0][1]
+    assert all(d == treedef for _, d in flat), "layers differ in structure"
+    columns = [list(col) for col in zip(*(leaves for leaves, _ in flat))]
+    del flat
+    stacked = []
+    for col in columns:
+        stacked.append(jnp.stack(col))
+        col.clear()
+    return jax.tree_util.tree_unflatten(treedef, stacked)

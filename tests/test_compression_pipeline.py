@@ -171,3 +171,90 @@ def test_otp_training_increases_mask_ratio_and_keeps_kl_low(model_and_calib):
     r_last = np.mean([h["mask_ratio"] for h in hist[-5:]])
     assert r_last > r_first, (r_first, r_last)  # sparsity pressure works
     assert np.isfinite(hist[-1]["kl"])
+
+
+def _full_layer_pass(params, tokens, cfg, eps_tokens=128):
+    """Calibration statistics and Eq. 6 eps the plain way: every layer's
+    whole block sliced at once, the MoE layer run over all its experts,
+    and each probe a full layer with one expert fake-quantized in the
+    stack."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    windows = tf.layer_windows_static(cfg, tokens.shape[1])
+    phi, w, inputs, eps = [], [], [], []
+    for l, p_l in enumerate(tf.iter_blocks(params, cfg)):
+        x, h2 = pipeline._block_parts(p_l, x, cfg, int(windows[l]))
+        inputs.append(np.asarray(h2.reshape(-1, cfg.d_model), np.float32))
+        out = moe_layer(p_l["moe"], h2, cfg)
+        stats = pipeline.significance.RouterStats(cfg.num_experts)
+        stats.update(np.asarray(out.topk_idx), np.asarray(out.topk_gates))
+        phi.append(stats.phi)
+        w.append(stats.w)
+        x = x + out.y
+        ex = p_l["moe"]["experts"]
+        h = jnp.asarray(inputs[-1][:eps_tokens])[None]
+        base = moe_layer(p_l["moe"], h, cfg).y
+        e_l = np.zeros((cfg.num_experts, 3))
+        for i in range(cfg.num_experts):
+            for j, bits in enumerate((1, 2, 3)):
+                q = pipeline._fake_quant_expert(
+                    {k: ex[k][i] for k in ex}, bits, cfg.quant.group)
+                probe = {k: jnp.stack([q[k] if e == i else ex[k][e]
+                                       for e in range(cfg.num_experts)])
+                         for k in ex}
+                y = moe_layer(dict(p_l["moe"], experts=probe), h, cfg).y
+                e_l[i, j] = float(jnp.linalg.norm((y - base).astype(jnp.float32)))
+        eps.append(e_l)
+    return np.stack(phi), np.stack(w), inputs, np.stack(eps)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_streamed_pass_equals_the_full_layer_pass(dtype):
+    """Calibration and eps, one expert of one layer at a time, give bit
+    for bit what the whole layer gives: routing does not depend on the
+    experts, and each expert's rows are computed alone either way."""
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    params = get_model(cfg).init(jax.random.PRNGKey(3))
+    tokens = jnp.asarray(
+        np.random.default_rng(3).integers(0, cfg.vocab_size, (2, 48)), jnp.int32)
+    calib = pipeline.calibrate(params, tokens, cfg)
+    eps = pipeline.compute_eps(params, calib, cfg, eps_tokens=128)
+    phi, w, inputs, eps_full = _full_layer_pass(params, tokens, cfg)
+    assert np.array_equal(calib.phi, phi) and np.array_equal(calib.w, w)
+    for got, want in zip(calib.moe_inputs, inputs):
+        assert np.array_equal(got, want)
+    assert eps.max() > 0 and np.array_equal(eps, eps_full)
+
+
+def test_pass_holds_no_layer_of_experts(monkeypatch):
+    """Beside the model, the pass's live device arrays stay under the
+    compressed output plus a few experts' float32 copies: no layer of
+    experts (8 here) is ever copied, in any phase."""
+    from repro.core import compressed_moe
+    from repro.models import moe as moe_mod
+
+    cfg = dataclasses.replace(CFG, d_ff_expert=512)
+    params = get_model(cfg).init(jax.random.PRNGKey(4))
+    tokens = jnp.asarray(
+        np.random.default_rng(4).integers(0, cfg.vocab_size, (2, 32)), jnp.int32)
+    live = lambda: sum(a.nbytes for a in jax.live_arrays())
+    samples = []
+
+    def sampled(fn):
+        def wrapper(*a, **kw):
+            samples.append(live())
+            return fn(*a, **kw)
+        return wrapper
+
+    monkeypatch.setattr(moe_mod, "expert_ffn", sampled(moe_mod.expert_ffn))
+    monkeypatch.setattr(pipeline, "quantize_to_packed",
+                        sampled(pipeline.quantize_to_packed))
+    monkeypatch.setattr(compressed_moe, "quantize_to_packed",
+                        sampled(compressed_moe.quantize_to_packed))
+    base = live()
+    calib = pipeline.calibrate(params, tokens, cfg)
+    served, _ = pipeline.compress_for_serving(params, calib, cfg)
+    expert_f32 = 3 * cfg.d_model * cfg.d_ff_expert * 4
+    out = sum(a.nbytes for a in jax.tree.leaves(served["blocks"]))
+    assert len(samples) > 3 * cfg.num_layers * cfg.num_experts
+    assert max(samples) - base <= out + 4 * expert_f32, (
+        max(samples) - base, out, expert_f32)

@@ -6,8 +6,10 @@ the kernels' numerics but not the TPU compiler's rules: block tiling,
 supported casts, VMEM size. Here each kernel on the serving path is
 compiled, not run, for a described v5e chip at moonshot-v1-16b-a3b's
 widths (d_model 2048, 16 KV heads x 128, 64 experts of d_ff 1408, group
-128; shared experts 2 x 1408), through the same ``repro.kernels.ops``
-wrappers and block choices the server uses.
+128; shared experts 2 x 1408) and at Mixtral-8x7B's (d_model 4096, 32
+query heads on 8 KV heads x 128, 8 experts of d_ff 14336, no shared
+expert), through the same ``repro.kernels.ops`` wrappers and block
+choices the server uses.
 
 The topology is described inside a module fixture, never at import, so
 every xdist worker collects the same tests and only the one that runs
@@ -22,6 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import ModelConfig, QuantConfig
 from repro.configs.registry import get_config
 from repro.core.compressed_moe import (
     BucketMeta,
@@ -36,6 +39,15 @@ D_MODEL, D_FF, HEAD_DIM, GROUP = 2048, 1408, 128, 128
 SLOTS = 8  # decode batch
 EXPERT_ROWS = 16  # one PMQ bucket's experts
 CAP = SLOTS * 6  # drop-free decode capacity: tokens x top-k
+# Mixtral-8x7B-v0.1 at published widths (2 of its 32 layers)
+MIXTRAL = ModelConfig(
+    name="mixtral-8x7b-v0.1", family="moe", num_layers=2, d_model=4096,
+    num_heads=32, num_kv_heads=8, head_dim=128, d_ff=14336,
+    d_ff_expert=14336, vocab_size=32000, num_experts=8, top_k=2,
+    num_shared_experts=0, rope_theta=1e6, norm_eps=1e-5,
+    moe_capacity_factor=8.0, quant=QuantConfig(enabled=True),
+)
+MIXTRAL_SLOTS = 64  # the decode batch of its benchmark cell
 
 
 @pytest.fixture(scope="module")
@@ -76,7 +88,8 @@ def _packed(e, k, n, bits):
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("hkv,g", [(16, 1), (8, 8)], ids=["mha16", "gqa8x8"])
+@pytest.mark.parametrize("hkv,g", [(16, 1), (8, 8), (8, 4)],
+                         ids=["mha16", "gqa8x8", "gqa8x4"])
 def test_paged_attention_compiles(one_chip, hkv, g, quant):
     nb, bs, mb = 80, 16, 10
     kv_dtype = jnp.uint8 if quant else jnp.bfloat16
@@ -131,10 +144,43 @@ def test_moe_gmm_compiles(one_chip, swiglu, k, n, bits):
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("bits", [1, 2, 3])
+@pytest.mark.parametrize("swiglu", [True, False], ids=["swiglu", "down"])
+def test_moe_gmm_compiles_at_mixtral_widths(one_chip, swiglu, bits):
+    # a bucket of 4 of the 8 wide experts, the rows of a 64-slot decode
+    # step's 128 routed pairs at drop-free capacity
+    d, f, e = MIXTRAL.d_model, MIXTRAL.d_ff_expert, 4
+    pairs = MIXTRAL_SLOTS * MIXTRAL.top_k
+    cap = pairs
+    bm = gmm_block_rows(cap)
+    m = grouped_extent(e, cap, pairs, bm)
+    k, n = (d, f) if swiglu else (f, d)
+    w = _packed(e, k, n, bits)
+    sz = ((e, k // GROUP, n), jnp.float32)
+    x = ((m, k), jnp.bfloat16)
+    be, na = ((m // bm,), jnp.int32), ((1,), jnp.int32)
+    if swiglu:
+        def fn(x, wg, wu, gs, gz, us, uz, be, na):
+            return ops.moe_gmm_swiglu(
+                x, wg, wu, gs, gz, us, uz, be, na,
+                bits=bits, group=GROUP, backend="pallas", bm=bm,
+            )
+        shapes = (x, w, w, sz, sz, sz, sz, be, na)
+    else:
+        def fn(x, w, s, z, be, na):
+            return ops.moe_gmm(
+                x, w, s, z, be, na, bits=bits, group=GROUP,
+                backend="pallas", bm=bm,
+            )
+        shapes = (x, w, sz, sz, be, na)
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
 @pytest.mark.parametrize(
     "k,n",
-    [(D_MODEL, D_MODEL), (D_MODEL, 2 * D_FF), (2 * D_FF, D_MODEL)],
-    ids=["attn", "shared_gate_up", "shared_down"],
+    [(D_MODEL, D_MODEL), (D_MODEL, 2 * D_FF), (2 * D_FF, D_MODEL),
+     (4096, 4096), (4096, 1024)],
+    ids=["attn", "shared_gate_up", "shared_down", "mixtral_qo", "mixtral_kv"],
 )
 def test_quant_matmul_4bit_compiles(one_chip, k, n):
     def fn(x, w, s, z):
@@ -188,6 +234,10 @@ def _served_param_specs(cfg, buckets, sharding=None):
         start += count
     hq, hkv, dh = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim, f
     fs = f * cfg.num_shared_experts
+    moe = {"router": {"w": sds((l, d, cfg.num_experts), jnp.float32)}}
+    if fs:
+        moe["shared"] = {"w_gate": dense(d, fs), "w_up": dense(d, fs),
+                         "w_down": dense(fs, d)}
     return {
         "embed": sds((cfg.vocab_size, d), fp),
         "final_norm": sds((d,), fp),
@@ -197,11 +247,7 @@ def _served_param_specs(cfg, buckets, sharding=None):
             "attn": {"wq": dense(d, hq), "wk": dense(d, hkv),
                      "wv": dense(d, hkv), "wo": dense(hq, d)},
             "ln2": sds((l, d), fp),
-            "moe": {
-                "router": {"w": sds((l, d, cfg.num_experts), jnp.float32)},
-                "shared": {"w_gate": dense(d, fs), "w_up": dense(d, fs),
-                           "w_down": dense(fs, d)},
-            },
+            "moe": moe,
             "moe_ce": CompressedExperts(
                 meta=tuple(meta),
                 slot_of_expert=sds((l, cfg.num_experts), jnp.int32),
@@ -265,3 +311,41 @@ def test_decode_megastep_compiles(one_chip, monkeypatch):
     ]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_mixtral_programs_compile(one_chip, monkeypatch):
+    """The engine's horizon-8 decode program and its 16-token prefill
+    chunk at Mixtral-8x7B's widths (2 of 32 layers, 64 slots, GQA 32/8,
+    8 experts in buckets of 3/1/4 at 1/2/3 bits, no shared expert) call
+    the Pallas kernels."""
+    from repro.serving.engine import _jitted_steps
+
+    monkeypatch.setattr(ops, "default_backend", lambda: "pallas")
+    cfg, b = MIXTRAL, MIXTRAL_SLOTS
+    params = _served_param_specs(cfg, [(1, 3), (2, 1), (3, 4)], one_chip)
+    blocks, bs = 41, 16
+    pool = ((cfg.num_layers, b * blocks, bs, cfg.num_kv_heads, HEAD_DIM),
+            jnp.bfloat16)
+    decode, prefill = _jitted_steps(cfg, True, None, 8, 0.0)
+
+    def dec(params, k, v, token, pos, tables, active, budgets, eos):
+        return decode(params, k, v, None, token, pos, tables, active,
+                      budgets, eos, None)
+
+    def pre(params, k, v, tokens, start, valid_len, row):
+        return prefill(params, k, v, None, tokens, start, valid_len, row)
+
+    def spec(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    i32 = jnp.int32
+    programs = [
+        (dec, [pool, pool, ((b, 1), i32), ((b,), i32), ((b, blocks), i32),
+               ((b,), jnp.bool_), ((b,), i32), ((b,), i32)]),
+        (pre, [pool, pool, ((1, bs), i32), ((), i32), ((), i32),
+               ((1, blocks), i32)]),
+    ]
+    for fn, shapes in programs:
+        args = [params] + [spec(s, dt) for s, dt in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text

@@ -131,6 +131,85 @@ def test_span_is_a_profiler_annotation_at_level_off(tmp_path):
     assert t.events == []
 
 
+@pytest.mark.parametrize("level", ["off", "spans"])
+def test_pmq_pass_spans_and_phase_seconds(level):
+    """The PMQ pass is one ``pmq.calibrate``, one ``pmq.eps`` and one
+    ``pmq.pack`` per layer, and one ``pmq.plan``, on the ``pmq`` track;
+    it sums each phase's seconds and reads the device's peak after each
+    layer's pack, at every level (the record only from ``spans``)."""
+    from repro.core import pipeline
+
+    cfg = dataclasses.replace(OFFLOAD_MOE, num_shared_experts=0)
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    tokens = jax.numpy.asarray(
+        np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 16)),
+        jax.numpy.int32)
+    tracer = SpanTracer(level)
+    calib = pipeline.calibrate(params, tokens, cfg, tracer=tracer)
+    pipeline.compress_for_serving(params, calib, cfg)
+    log = calib.log
+    assert log.tracer is tracer
+    phases = ("pmq.calibrate", "pmq.eps", "pmq.plan", "pmq.pack")
+    assert set(log.seconds) == set(phases)
+    assert all(t > 0 for t in log.seconds.values())
+    assert len(log.peak_bytes) == cfg.num_layers
+    names = [e["name"] for e in tracer.events]
+    if level == "off":
+        assert names == []
+        return
+    assert sorted(names) == sorted(
+        ["pmq.calibrate", "pmq.plan"] + ["pmq.eps", "pmq.pack"] * cfg.num_layers)
+    assert all(e["track"] == "pmq" and e["ph"] == "X" for e in tracer.events)
+    for name in ("pmq.eps", "pmq.pack"):
+        assert [e["args"]["layer"] for e in tracer.events
+                if e["name"] == name] == list(range(cfg.num_layers))
+    for name in phases:
+        total = sum(e["dur_us"] for e in tracer.events if e["name"] == name)
+        assert abs(total * 1e-6 - log.seconds[name]) < 1e-3
+    validate_events(tracer.events)
+
+
+def test_pmq_log_peaks_are_the_latest_packings():
+    """Packing one calibration twice (as a bit-width sweep does) keeps one
+    peak per layer, of the latest packing, and adds both packings' seconds
+    and spans up."""
+    from repro.core import pipeline
+
+    cfg = dataclasses.replace(OFFLOAD_MOE, num_shared_experts=0)
+    params = get_model(cfg).init(jax.random.PRNGKey(1))
+    tokens = jax.numpy.asarray(
+        np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 16)),
+        jax.numpy.int32)
+    tracer = SpanTracer("spans")
+    calib = pipeline.calibrate(params, tokens, cfg, tracer=tracer)
+    pipeline.compress_for_serving(params, calib, cfg)
+    once = calib.log.seconds["pmq.pack"]
+    pipeline.compress_for_serving(params, calib, cfg)
+    assert len(calib.log.peak_bytes) == cfg.num_layers
+    assert calib.log.seconds["pmq.pack"] > once
+    assert sum(e["name"] == "pmq.pack" for e in tracer.events) == 2 * cfg.num_layers
+
+
+def test_tracer_sits_below_serving_and_the_pmq_pass():
+    """The PMQ pass and the span tracer load without the serving package:
+    the offline compression pass does not depend on serving."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys, repro.core.pipeline, repro.trace; "
+            "print(sorted(m for m in sys.modules if m.startswith('repro.serving')))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout
+    assert out.strip() == "[]", out
+    from repro import trace
+    from repro.serving import trace as serving_trace
+
+    assert serving_trace.SpanTracer is trace.SpanTracer
+    assert serving_trace.NULL_TRACER is trace.NULL_TRACER
+
+
 def test_deterministic_projection_strips_wall_clock_only():
     t = SpanTracer("full")
     with t.span("decode", track="slot0", cat="decode", rid=3):
