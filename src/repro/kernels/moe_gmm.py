@@ -4,12 +4,42 @@ MegaBlocks-style dropless expert GEMM adapted to TPU + PMQ quantization
 (DESIGN.md §5.4): tokens are pre-sorted by expert id and padded so each
 expert's row range is a multiple of ``bm``; a scalar-prefetch array
 ``block_expert [M/bm]`` tells each row-block which expert's packed weight
-tile to fetch. Dequantization (group-wise affine over K) happens in VMEM
-exactly as in :mod:`repro.kernels.quant_matmul`.
+tile to fetch.
 
 Because every PMQ bit-width rides the same (scale, zero) affine form
 (1-bit: scale=2α, zero=0.5 — see ``quantize_to_packed``), a *bit-bucketed*
 MoE layer issues one ``moe_gmm`` per bucket with experts of equal width.
+
+**Dequantisation: the products, not the weights.** With group-wise
+affine codes, group ``g`` of ``group`` K rows gives
+
+    x_g @ ((q_g − z_g)·s_g) = s_g ⊙ (x_g @ q_g − z_g ⊙ rowsum(x_g))
+
+so a live grid step never forms a float weight. Per group it unpacks the
+codes, centred as ``q_g − c`` (``c`` is ``2^(bits−1)``, and 0 for 1-bit
+codes; exact in bf16, whose 8 significant bits hold every 4-bit code),
+takes one MXU product of
+depth ``group`` into a ``[bm, bn]`` f32 partial, and applies ``s_g`` and
+``(z_g − c)·rowsum(x_g)`` to that partial. The vector unit does two
+shifts and a convert per weight; the affine work shrinks from ``bk·bn``
+to ``bm·bn·bk/group`` elements. Every caller uses
+``bm ≤ group`` (``gmm_block_rows`` targets 16), where the epilogue costs
+at most what the per-weight affine did. Products accumulate in f32 from
+unrounded codes, so the result is at least as exact as dequantising the
+weights to the compute dtype first.
+
+**Plane order.** A packed byte row holds ``per = 8/bits`` consecutive K
+rows, the ``i``-th at bit ``i·bits``. Each bit plane, the ``i``-th field
+of every byte, is unpacked as its own dense ``[group/per, bn]`` tile, and the
+planes are stacked plane-major: no ``[.., per, bn]`` broadcast and no
+sublane-interleaving reshape. So within each group of ``group`` columns
+``x`` must arrive in the same order: position ``i·(group/per) + r`` holds
+column ``r·per + i`` (:func:`_x_planes`). The ``*_pallas`` entry points
+take ``x`` in its natural order and transpose its columns once per call,
+in XLA, before the kernel. 3-bit codes are ``2·hi + lo`` from a 2-bit plane
+pair (``per`` 4) and a 1-bit plane (``per`` 8), which come out in
+different orders: the kernel is given ``x`` in both and adds
+``2·(x₄ @ hi) + x₈ @ lo`` per group, so neither plane is reordered.
 
 **Ragged-length handling**: the caller
 (:mod:`repro.core.compressed_moe`) gives the kernel one row per routed
@@ -19,7 +49,7 @@ routed pairs (``grouped_extent``), not the experts' capacity. The grid
 follows ``x_sorted.shape[0]``, so it walks about ``experts + pairs/bm``
 row blocks. ``num_active [1]`` (second scalar-prefetch operand) tells
 the kernel how many leading row-blocks carry tokens; blocks past it skip
-the unpack/dequant/MXU work and write zeros, so the jitted program keeps
+the unpack and the MXU work and write zeros, so the jitted program keeps
 its static shape whatever the routing.
 
 **SwiGLU epilogue** (:func:`moe_gmm_swiglu_pallas`): the gate and up
@@ -30,13 +60,17 @@ GEMMs and ``x`` streams from HBM once instead of twice.
 
 Layouts
 -------
-* ``x_sorted``:  [Mp, K]   tokens sorted by expert, bm-padded per expert
-* ``w_packed``:  [E, K/per, N] uint8 (or (hi [E,K/4,N], lo [E,K/8,N]) for 3-bit)
+* ``x_sorted``:  [Mp, K]   tokens sorted by expert, bm-padded per expert;
+  the kernel reads it in plane order (one copy per plane width)
+* ``w_packed``:  [E, K/per, N] uint8 (or (hi [E,K/4,N], lo [E,K/8,N]) for 3-bit),
+  the PMQ packed format of :mod:`repro.core.packing`, unchanged
 * ``scale/zero``:[E, K/group, N] f32
 * ``block_expert``: [Mp/bm] int32 — expert id per row-block (scalar prefetch)
 * ``num_active``: [1] int32 — row-blocks carrying routed tokens (scalar
   prefetch; blocks ≥ it are skipped and zero-filled)
-* grid (Mp/bm, N/bn, K/bk), K innermost, f32 scratch accumulator.
+* grid (Mp/bm, N/bn, K/bk), K innermost, f32 scratch accumulator; ``bk``
+  a multiple of ``group``, which is a multiple of ``per`` (on the TPU of
+  ``8·per``, so each plane's rows of a group fill whole int32 tiles).
 """
 from __future__ import annotations
 
@@ -48,8 +82,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .quant_matmul import _dequant, _unpack_tile
-
 __all__ = [
     "moe_gmm_pallas",
     "moe_gmm_swiglu_pallas",
@@ -58,32 +90,109 @@ __all__ = [
 ]
 
 
+def _pers(bits: int) -> Tuple[int, ...]:
+    """Codes per byte of each packed plane operand (3-bit: hi, lo)."""
+    return (4, 8) if bits == 3 else (8 // bits,)
+
+
+def _x_planes(x: jnp.ndarray, bits: int, group: int):
+    """``x`` in each plane operand's column order: within each group,
+    position ``i·(group/per) + r`` takes column ``r·per + i`` (plane-major,
+    byte row within plane), a transpose of ``[group/per, per]`` blocks."""
+    m, k = x.shape
+    return [
+        x.reshape(m, k // group, group // per, per).swapaxes(2, 3).reshape(m, k)
+        for per in _pers(bits)
+    ]
+
+
 def _w_specs_and_planes(w_packed, bits: int, bk: int, bn: int):
     """BlockSpecs + flat plane list for one packed weight operand."""
-    if bits == 3:
-        hi, lo = w_packed
-        specs = [
-            pl.BlockSpec((1, bk // 4, bn), lambda i, j, kk, be, na: (be[i], kk, j)),
-            pl.BlockSpec((1, bk // 8, bn), lambda i, j, kk, be, na: (be[i], kk, j)),
-        ]
-        return specs, [hi, lo]
-    per = 8 // bits
+    planes = list(w_packed) if bits == 3 else [w_packed]
     specs = [
         pl.BlockSpec((1, bk // per, bn), lambda i, j, kk, be, na: (be[i], kk, j))
+        for per in _pers(bits)
     ]
-    return specs, [w_packed]
+    return specs, planes
 
 
-def _take_w_tile(refs, bits: int):
-    """Pop one weight operand's refs and present it to ``_unpack_tile``."""
-    if bits == 3:
-        (hi_ref, lo_ref), rest = refs[:2], refs[2:]
-        return (_Squeezed(hi_ref), _Squeezed(lo_ref)), rest
-    return _Squeezed(refs[0]), refs[1:]
+def _group_codes(packed: jnp.ndarray, per: int, g: int, group: int, dtype):
+    """Group ``g``'s codes ``[group, bn]`` in plane order from an int32
+    ``[bk/per, bn]`` tile of packed bytes: one dense two-op extract of
+    each plane over the group's ``group/per`` byte rows, stacked
+    plane-major.
+
+    A multi-bit field is extracted sign-extended (to the word's top by a
+    left shift, down by an arithmetic right shift) after each field's top
+    bit is flipped once per byte, which gives ``q − 2^(bits−1)``: centred
+    codes, so the f32 partial products stay small, for the two shifts an
+    unsigned extract (shift, mask) would take. 1-bit codes stay 0/1.
+    """
+    rows = group // per
+    bits = 8 // per
+    byte = packed[g * rows:(g + 1) * rows]
+    planes = []
+    if bits == 1:
+        for i in range(per):
+            v = byte >> i if i else byte
+            planes.append(v & 1 if i < per - 1 else v)
+    else:
+        tops = sum(1 << (i * bits + bits - 1) for i in range(per))
+        word = (byte ^ tops) << 24
+        for i in range(per):
+            up = 8 - (i + 1) * bits
+            planes.append((word << up if up else word) >> (32 - bits))
+    return jnp.concatenate(
+        [v.astype(jnp.float32).astype(dtype) for v in planes], axis=0
+    )
+
+
+def _accumulate(acc_ref, xs, rowsums, w_refs, s_ref, z_ref, *, bits, group,
+                dtype):
+    """``acc += x @ dequant(W)`` for one grid step, scaled per group after
+    the MXU (module docstring). ``xs`` holds the x tile in each plane
+    operand's order; ``rowsums`` each group's ``[bm, 1]`` f32 row sums."""
+    packed = [w[0].astype(jnp.int32) for w in w_refs]
+    s_t, z_t = s_ref[0], z_ref[0]
+    # what the unpacked codes are offset by: multi-bit fields come out as
+    # q − 2^(bits−1), 1-bit ones as q, so 3-bit 2·hi + lo is q − 4
+    centre = 1 << (bits - 1) if bits > 1 else 0
+    total = None
+    for g in range(s_t.shape[0]):
+        cols = slice(g * group, (g + 1) * group)
+        prods = [
+            jnp.dot(
+                x[:, cols], _group_codes(p, per, g, group, dtype),
+                preferred_element_type=jnp.float32,
+            )
+            for x, p, per in zip(xs, packed, _pers(bits))
+        ]
+        prod = 2.0 * prods[0] + prods[1] if bits == 3 else prods[0]
+        s_g, z_g = s_t[g:g + 1], z_t[g:g + 1]
+        part = (prod - rowsums[g] * (z_g - centre)) * s_g
+        total = part if total is None else total + part
+    acc_ref[...] += total
+
+
+def _x_tiles(x_refs, group: int, dtype):
+    """The step's x tiles in the compute dtype and each group's row sums
+    ``[bm, 1]`` in f32 (a sum does not depend on the column order)."""
+    xs = [r[...].astype(dtype) for r in x_refs]
+    xf = xs[-1].astype(jnp.float32)
+    rowsums = [
+        jnp.sum(xf[:, g:g + group], axis=1, keepdims=True)
+        for g in range(0, xf.shape[1], group)
+    ]
+    return xs, rowsums
 
 
 def _full_blocks(m: int, bm: int) -> jnp.ndarray:
     return jnp.full((1,), m // bm, jnp.int32)
+
+
+def _check_group(bits: int, group: int, bk: int):
+    assert bk % group == 0
+    assert all(group % per == 0 for per in _pers(bits)), (bits, group)
 
 
 @functools.partial(
@@ -108,58 +217,52 @@ def moe_gmm_pallas(
 ) -> jnp.ndarray:
     """Block-diagonal grouped GEMM: row-block i uses expert block_expert[i].
 
-    ``num_active [1]`` (optional) marks how many leading row-blocks carry
-    routed tokens; blocks past it are zero-filled without touching the
-    MXU (ragged capacity layouts pass the bm-padded routed-row count).
+    ``x_sorted`` is in its natural column order; it is transposed into
+    plane order here, once per call (module docstring). ``num_active [1]``
+    (optional) marks how many leading row-blocks carry routed tokens;
+    blocks past it are zero-filled without touching the MXU (ragged
+    capacity layouts pass the bm-padded routed-row count).
     """
     m, k = x_sorted.shape
-    if bits == 3:
-        hi, lo = w_packed
-        e, _, n = hi.shape
-    else:
-        e, _, n = w_packed.shape
+    e, _, n = (w_packed[0] if bits == 3 else w_packed).shape
     out_dtype = out_dtype or x_sorted.dtype
     bn, bk = min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
-    assert bk % group == 0
+    _check_group(bits, group, bk)
     assert block_expert.shape == (m // bm,)
     if num_active is None:
         num_active = _full_blocks(m, bm)
     nk = k // bk
     grid = (m // bm, n // bn, nk)
 
+    xs = _x_planes(x_sorted, bits, group)
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk, be, na: (i, kk))
     s_spec = pl.BlockSpec(
         (1, bk // group, bn), lambda i, j, kk, be, na: (be[i], kk, j)
     )
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk, be, na: (i, j))
     w_specs, planes = _w_specs_and_planes(w_packed, bits, bk, bn)
-    args = (block_expert, num_active, x_sorted, *planes, scale, zero)
+    nx, nw = len(xs), len(planes)
+    args = (block_expert, num_active, *xs, *planes, scale, zero)
 
     compute_dtype = jnp.float32 if x_sorted.dtype == jnp.float32 else jnp.bfloat16
 
-    def kernel(be_ref, na_ref, x_ref, *rest):
-        # squeeze the leading expert dim of the weight/scale tiles
-        w_tile, rest = _take_w_tile(list(rest), bits)
-        s_ref, z_ref, o_ref, acc_ref = rest
-        s_t, z_t = s_ref[0], z_ref[0]
+    def kernel(be_ref, na_ref, *rest):
+        x_refs, w_refs = rest[:nx], rest[nx:nx + nw]
+        s_ref, z_ref, o_ref, acc_ref = rest[nx + nw:]
 
         @pl.when(pl.program_id(2) == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        # ragged skip: blocks past the routed-row frontier never unpack,
-        # dequantize or touch the MXU — their accumulator stays zero
+        # ragged skip: blocks past the routed-row frontier never unpack or
+        # touch the MXU — their accumulator stays zero
         @pl.when(pl.program_id(0) < na_ref[0])
         def _compute():
-            bk_ = x_ref.shape[1]
-            bn_ = o_ref.shape[1]
-            codes = _unpack_tile(w_tile, bits, bk_, bn_)
-            w = _dequant(codes, s_t, z_t, group, compute_dtype)
-            acc_ref[...] += jnp.dot(
-                x_ref[...].astype(compute_dtype),
-                w,
-                preferred_element_type=jnp.float32,
+            xt, rowsums = _x_tiles(x_refs, group, compute_dtype)
+            _accumulate(
+                acc_ref, xt, rowsums, w_refs, s_ref, z_ref,
+                bits=bits, group=group, dtype=compute_dtype,
             )
 
         @pl.when(pl.program_id(2) == nk - 1)
@@ -169,7 +272,7 @@ def moe_gmm_pallas(
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[x_spec, *w_specs, s_spec, s_spec],
+        in_specs=[*[x_spec] * nx, *w_specs, s_spec, s_spec],
         out_specs=o_spec,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
@@ -211,26 +314,25 @@ def moe_gmm_swiglu_pallas(
 
     ``y = silu(x @ dequant(Wg)) * (x @ dequant(Wu))`` per row-block's
     expert. The two projections accumulate in separate VMEM scratches
-    off a single streamed ``x`` tile; the nonlinearity runs on the f32
+    off a single streamed ``x`` tile (in plane order, as in
+    :func:`moe_gmm_pallas`); the nonlinearity runs on the f32
     accumulators right before the one output write, so the [M, F] hidden
     never exists in HBM. Same ragged ``num_active`` semantics as
     :func:`moe_gmm_pallas`.
     """
     m, k = x_sorted.shape
-    if bits == 3:
-        e, _, n = wg_packed[0].shape
-    else:
-        e, _, n = wg_packed.shape
+    e, _, n = (wg_packed[0] if bits == 3 else wg_packed).shape
     out_dtype = out_dtype or x_sorted.dtype
     bn, bk = min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
-    assert bk % group == 0
+    _check_group(bits, group, bk)
     assert block_expert.shape == (m // bm,)
     if num_active is None:
         num_active = _full_blocks(m, bm)
     nk = k // bk
     grid = (m // bm, n // bn, nk)
 
+    xs = _x_planes(x_sorted, bits, group)
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk, be, na: (i, kk))
     s_spec = pl.BlockSpec(
         (1, bk // group, bn), lambda i, j, kk, be, na: (be[i], kk, j)
@@ -238,17 +340,20 @@ def moe_gmm_swiglu_pallas(
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk, be, na: (i, j))
     g_specs, g_planes = _w_specs_and_planes(wg_packed, bits, bk, bn)
     u_specs, u_planes = _w_specs_and_planes(wu_packed, bits, bk, bn)
+    nx, nw = len(xs), len(g_planes)
     args = (
-        block_expert, num_active, x_sorted, *g_planes, *u_planes,
+        block_expert, num_active, *xs, *g_planes, *u_planes,
         g_scale, g_zero, u_scale, u_zero,
     )
 
     compute_dtype = jnp.float32 if x_sorted.dtype == jnp.float32 else jnp.bfloat16
 
-    def kernel(be_ref, na_ref, x_ref, *rest):
-        g_tile, rest = _take_w_tile(list(rest), bits)
-        u_tile, rest = _take_w_tile(rest, bits)
-        gs_ref, gz_ref, us_ref, uz_ref, o_ref, accg_ref, accu_ref = rest
+    def kernel(be_ref, na_ref, *rest):
+        x_refs = rest[:nx]
+        g_refs, u_refs = rest[nx:nx + nw], rest[nx + nw:nx + 2 * nw]
+        gs_ref, gz_ref, us_ref, uz_ref, o_ref, accg_ref, accu_ref = (
+            rest[nx + 2 * nw:]
+        )
 
         @pl.when(pl.program_id(2) == 0)
         def _init():
@@ -257,19 +362,15 @@ def moe_gmm_swiglu_pallas(
 
         @pl.when(pl.program_id(0) < na_ref[0])
         def _compute():
-            bk_ = x_ref.shape[1]
-            bn_ = o_ref.shape[1]
-            xt = x_ref[...].astype(compute_dtype)
-            wg = _dequant(
-                _unpack_tile(g_tile, bits, bk_, bn_),
-                gs_ref[0], gz_ref[0], group, compute_dtype,
-            )
-            accg_ref[...] += jnp.dot(xt, wg, preferred_element_type=jnp.float32)
-            wu = _dequant(
-                _unpack_tile(u_tile, bits, bk_, bn_),
-                us_ref[0], uz_ref[0], group, compute_dtype,
-            )
-            accu_ref[...] += jnp.dot(xt, wu, preferred_element_type=jnp.float32)
+            xt, rowsums = _x_tiles(x_refs, group, compute_dtype)
+            for acc_ref, w_refs, s_ref, z_ref in (
+                (accg_ref, g_refs, gs_ref, gz_ref),
+                (accu_ref, u_refs, us_ref, uz_ref),
+            ):
+                _accumulate(
+                    acc_ref, xt, rowsums, w_refs, s_ref, z_ref,
+                    bits=bits, group=group, dtype=compute_dtype,
+                )
 
         @pl.when(pl.program_id(2) == nk - 1)
         def _done():
@@ -279,7 +380,7 @@ def moe_gmm_swiglu_pallas(
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[x_spec, *g_specs, *u_specs, s_spec, s_spec, s_spec, s_spec],
+        in_specs=[*[x_spec] * nx, *g_specs, *u_specs, s_spec, s_spec, s_spec, s_spec],
         out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
@@ -295,20 +396,6 @@ def moe_gmm_swiglu_pallas(
         ),
         interpret=interpret,
     )(*args)
-
-
-class _Squeezed:
-    """Adapter presenting ``ref[0]`` as a 2-D ref for ``_unpack_tile``."""
-
-    def __init__(self, ref):
-        self._ref = ref
-
-    def __getitem__(self, idx):
-        return self._ref[0][idx] if idx is not Ellipsis else self._ref[0]
-
-    @property
-    def shape(self):
-        return self._ref.shape[1:]
 
 
 def sort_by_expert(

@@ -203,7 +203,14 @@ def moe_gmm(
     bk: int = 512,
 ) -> jnp.ndarray:
     """Grouped expert GEMM; ``num_active`` enables the ragged skip of
-    row-blocks past the routed-token frontier (see moe_gmm.py)."""
+    row-blocks past the routed-token frontier (see moe_gmm.py).
+
+    ``x_padded`` is in its natural column order. The Pallas path reorders
+    its columns once per call, by a transpose, into the order in which the
+    kernel unpacks the bit planes (within each quantisation group:
+    plane-major, byte row within plane; for 3-bit, one copy per plane
+    width), and scales each group's MXU product instead of each weight.
+    The packed weights are read as stored."""
     backend = backend or default_backend()
     if backend == "ref":
         return ref.moe_gmm_ref(
@@ -238,7 +245,11 @@ def moe_gmm_swiglu(
     bk: int = 512,
 ) -> jnp.ndarray:
     """Fused gate/up grouped GEMM + SwiGLU epilogue (one x stream, the
-    [M, F] hidden never round-trips HBM between the two projections)."""
+    [M, F] hidden never round-trips HBM between the two projections).
+
+    ``x_padded`` is reordered into plane order as in :func:`moe_gmm`; the
+    hidden it returns is in natural order, so the down projection's
+    :func:`moe_gmm` reorders it again for its own planes."""
     backend = backend or default_backend()
     if backend == "ref":
         return ref.moe_gmm_swiglu_ref(

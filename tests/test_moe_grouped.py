@@ -417,6 +417,21 @@ def test_moe_gmm_num_active_skips_blocks(bits):
     )
 
 
+def _f64_projection(x, packed, scale, zero, be, bits, bm):
+    """``x @ W`` per row block's expert in float64 over the oracle's
+    dequantised weights, and the f32 rounding bound of a ``k``-term dot
+    product against it, ``sqrt(k)·u·(|x| @ |W|)`` (u = 2^-24)."""
+    k = x.shape[1]
+    planes = list(zip(*packed)) if bits == 3 else list(packed)
+    w = np.stack([
+        np.asarray(ref.dequant_ref(p, s_, z_, bits, k), np.float64)
+        for p, s_, z_ in zip(planes, scale, zero)
+    ])[np.repeat(np.asarray(be), bm)]
+    xf = np.asarray(x, np.float64)
+    bound = np.sqrt(k) * 2.0 ** -24 * np.einsum("mk,mkn->mn", np.abs(xf), np.abs(w))
+    return np.einsum("mk,mkn->mn", xf, w), bound
+
+
 @pytest.mark.parametrize("bits", [2, 3])
 def test_moe_gmm_swiglu_matches_oracle(bits):
     e, k, n, bm = 3, 128, 128, 8
@@ -441,9 +456,38 @@ def test_moe_gmm_swiglu_matches_oracle(bits):
         x, gp, up, gs, gz, us, uz, be, na,
         bits=bits, group=128, backend="interpret", bm=bm,
     )
-    np.testing.assert_allclose(
-        np.asarray(y), np.asarray(y_ref), rtol=2e-5, atol=2e-5
+    # each projection, by the plain kernel, is the oracle's at 2e-5
+    g64, eg = _f64_projection(x, gp, gs, gz, be, bits, bm)
+    u64, eu = _f64_projection(x, up, us, uz, be, bits, bm)
+    kw = dict(bits=bits, group=128, bm=bm)
+    for w, s_, z_, p64, e_p in ((gp, gs, gz, g64, eg), (up, us, uz, u64, eu)):
+        p32 = np.asarray(ref.moe_gmm_ref(x, w, s_, z_, be, na, **kw))
+        np.testing.assert_allclose(
+            np.asarray(ops.moe_gmm(x, w, s_, z_, be, na, backend="interpret",
+                                   **kw)),
+            p32, rtol=2e-5, atol=2e-5,
+        )
+        np.testing.assert_array_less(np.abs(p32 - p64)[: 3 * bm],
+                                     e_p[: 3 * bm])
+    # y against the oracle, within what f32 rounding can move each. The
+    # kernel scales each group's MXU product and the oracle each weight,
+    # so they round differently, and silu(g)·u carries a rounding in u
+    # times |g| (up to ~50 here): against float64 maths the oracle itself
+    # is off by up to 4x the 2e-5 of its composition check above. So each
+    # projection's rounding, sqrt(k)·u·(|x| @ |W|) against a float64
+    # evaluation of the dequantised weights (checked on the oracle just
+    # above), is carried through silu(g)·u for each side, on top of 2e-5.
+    silu_g = g64 / (1.0 + np.exp(-g64))
+    carry = 1.1 * eg * np.abs(u64) + np.abs(silu_g) * eu + 1.1 * eg * eu
+    y_ref = np.asarray(y_ref)
+    np.testing.assert_array_less(
+        np.abs(np.asarray(y) - y_ref),
+        2e-5 + 2e-5 * np.abs(y_ref) + 2.0 * carry,
     )
+    # and y against float64 maths, within its own side's rounding alone
+    y64 = silu_g * u64
+    err = np.abs(np.asarray(y) - y64)[: 3 * bm]
+    assert np.all(err <= (carry + 2.0 ** -23 * np.abs(y64))[: 3 * bm])
     np.testing.assert_array_equal(np.asarray(y)[3 * bm :], 0.0)
 
 
